@@ -6,14 +6,16 @@ Sweeps the macro's precision operating points (r_in x r_w) through the
 precision-specialized kernel variants, reporting per-precision wall-clock,
 achieved integer-op rate, and bit-exactness against the oracle — the
 software analogue of the paper's Fig. 22 sweep.  The scaling sweep
-additionally shards the engine across 1/2/4/8 (emulated) devices — when
-run as a script the process requests 8 fake CPU devices via XLA_FLAGS
-*before* jax initializes, so CPU-only CI exercises the multi-macro
-dispatch."""
+additionally shards the engine across 1/2/4/8 devices.  Run as a script
+with JAX_PLATFORMS=cpu, the process requests 8 fake CPU devices via
+XLA_FLAGS *before* jax initializes, so CPU-only CI exercises the
+multi-macro dispatch; on a chip the sweep uses the devices that exist and
+prints how many."""
 import os
 import time
 
-if __name__ == "__main__":      # must precede the first jax import
+# must precede the first jax import
+if __name__ == "__main__" and os.environ.get("JAX_PLATFORMS") == "cpu":
     _flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in _flags:
         os.environ["XLA_FLAGS"] = (
@@ -700,6 +702,8 @@ def main(serving_only=False):
         ok &= det
         print(f"noise_engine_x{scale:g},{us:.0f},"
               f"acc{acc:.2f}_deterministic{det}")
+    devs = jax.devices()
+    print(f"shard_engine_devices,0,{devs[0].platform}x{len(devs)}")
     t_serial, srows = bench_scaling_sweep()
     print(f"shard_engine_serial,{t_serial:.0f}")
     for d, t_strong, t_weak, eff, match in srows:

@@ -12,9 +12,11 @@ import traceback
 from benchmarks import (fig3_abn_accuracy, fig6_split_dpl, fig8_settling,
                         fig10_20_nonidealities, fig13_adc, fig17_macro,
                         fig22_efficiency, kernel_bench, table1)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     suites = [
         ("fig6_split_dpl", fig6_split_dpl.main),
         ("fig8_settling", fig8_settling.main),

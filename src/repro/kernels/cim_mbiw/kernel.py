@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.jax_compat import tpu_compiler_params
+from repro.kernels.platform import platform_call
 
 
 def plane_layout(r_in: int) -> tuple[int, int]:
@@ -51,9 +51,9 @@ def plane_layout(r_in: int) -> tuple[int, int]:
     return shift, -(-r_in // shift)
 
 
-def _cim_mbiw_kernel(x_ref, w_ref, gamma_ref, beta_ref, o_ref, acc_ref, *,
+def _cim_mbiw_kernel(x_ref, w_ref, gain_ref, beta_ref, o_ref, acc_ref, *,
                      n_k_total: int, n_k_inner: int, plane_shift: int,
-                     g0: float, r_out: int, fuse_adc: bool):
+                     r_out: int, fuse_adc: bool, interpret: bool):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -75,28 +75,26 @@ def _cim_mbiw_kernel(x_ref, w_ref, gamma_ref, beta_ref, o_ref, acc_ref, *,
             o_ref[...] = acc_ref[...]
             return
         dp = acc_ref[...].astype(jnp.float32)
-        gamma = gamma_ref[...].astype(jnp.float32)      # (1, bn)
-        beta = beta_ref[...].astype(jnp.float32)        # (1, bn) or (bm, bn)
         mid = 2.0 ** (r_out - 1)
-        # Pin both float intermediates of the floor argument: XLA may
-        # FMA-contract `gain*dp + (mid+beta)` in some fusion contexts (e.g.
-        # inside a scan body) but not others, flipping codes where the
-        # product needs rounding.  ref.py computes the identical barriered
-        # chain — the float-op lockstep contract.
-        gain = jax.lax.optimization_barrier(gamma * g0)
-        t = jax.lax.optimization_barrier(gain * dp)
-        code = jnp.floor(mid + t + beta)
+        # float-op lockstep with ref.py: one rounded product, then
+        # (mid + t) + beta.  The interpreter's XLA backend may FMA-contract
+        # the product into the add in some fusion contexts (e.g. inside a
+        # scan body) but not others, so there the product is pinned;
+        # Mosaic keeps the two ops apart and has no lowering for the pin.
+        t = gain_ref[...] * dp                         # (1, bn) * (bm, bn)
+        if interpret:
+            t = jax.lax.optimization_barrier(t)
+        code = jnp.floor(mid + t + beta_ref[...])      # beta (1|bm, bn)
         o_ref[...] = jnp.clip(code, 0.0, 2.0 ** r_out - 1.0
                               ).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "plane_shift", "g0", "r_out", "bm", "bn", "bk", "interpret", "fuse_adc"))
+    "plane_shift", "g0", "r_out", "bm", "bn", "bk", "fuse_adc"))
 def cim_mbiw_matmul_planes(x_planes: jnp.ndarray, w_q: jnp.ndarray,
                            gamma: jnp.ndarray, beta: jnp.ndarray, *,
                            plane_shift: int, g0: float, r_out: int,
                            bm: int = 256, bn: int = 256, bk: int = 512,
-                           interpret: bool = True,
                            fuse_adc: bool = True) -> jnp.ndarray:
     """CIM matmul over input planes; shapes pre-padded to block multiples.
 
@@ -111,6 +109,9 @@ def cim_mbiw_matmul_planes(x_planes: jnp.ndarray, w_q: jnp.ndarray,
     returns  : (M, N) int32 ADC codes in [0, 2^r_out - 1], or the raw int32
                dp accumulator when `fuse_adc=False` (the noise-injected
                engine applies its own ADC epilogue after the kernel)
+
+    The kernel is compiled by Mosaic in a program lowered for a TPU and
+    interpreted in one lowered for the CPU (kernels/platform.py).
     """
     m, pk = x_planes.shape
     k_dim, n = w_q.shape
@@ -120,26 +121,35 @@ def cim_mbiw_matmul_planes(x_planes: jnp.ndarray, w_q: jnp.ndarray,
     assert beta.shape in ((1, n), (m, n)), (beta.shape, m, n)
     n_k_inner = k_dim // bk
     n_k_total = n_planes * n_k_inner
+    # the ADC gain, computed (and pinned) outside the kernel exactly as
+    # ref.py computes it
+    gain = jax.lax.optimization_barrier(gamma * g0)
 
     beta_spec = (pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
                  if beta.shape[0] == m and m != 1 else
                  pl.BlockSpec((1, bn), lambda i, j, k: (0, j)))
-    kernel = functools.partial(
-        _cim_mbiw_kernel, n_k_total=n_k_total, n_k_inner=n_k_inner,
-        plane_shift=plane_shift, g0=g0, r_out=r_out, fuse_adc=fuse_adc)
-    return pl.pallas_call(
-        kernel,
-        grid=(m // bm, n // bn, n_k_total),
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k % n_k_inner, j)),
-            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
-            beta_spec,
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(x_planes, w_q, gamma, beta)
+
+    def build(interpret: bool):
+        kernel = functools.partial(
+            _cim_mbiw_kernel, n_k_total=n_k_total, n_k_inner=n_k_inner,
+            plane_shift=plane_shift, r_out=r_out, fuse_adc=fuse_adc,
+            interpret=interpret)
+        return pl.pallas_call(
+            kernel,
+            grid=(m // bm, n // bn, n_k_total),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
+                pl.BlockSpec((bk, bn), lambda i, j, k: (k % n_k_inner, j)),
+                pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+                beta_spec,
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="cim_mbiw",
+        )
+
+    return platform_call(build, x_planes, w_q, gain, beta)

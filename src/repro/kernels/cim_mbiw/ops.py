@@ -101,8 +101,7 @@ def split_planes(x_q: jnp.ndarray, r_in: int,
 
 
 def kernel_variant(prec: KernelPrecision, bm: int = 256, bn: int = 256,
-                   bk: int = 512, interpret: bool = True,
-                   fuse_adc: bool = True) -> Callable:
+                   bk: int = 512, fuse_adc: bool = True) -> Callable:
     """Precision-specialized kernel callable (cached per operating point).
 
     Returned fn: (x_q (M,K) uint<2^r_in, w_q (K,N) odd ints, gamma (N,),
@@ -117,13 +116,38 @@ def kernel_variant(prec: KernelPrecision, bm: int = 256, bn: int = 256,
     """
     shift, n_planes = plane_layout(prec.r_in)
     return _kernel_variant(shift, n_planes, prec.r_out, bm, bn, bk,
-                           interpret, fuse_adc)
+                           fuse_adc)
 
 
-def _clamp_block(pref: int, dim: int, align: int = 8) -> int:
-    """Largest useful block for `dim`: `pref` capped at dim rounded up to
-    `align` (Pallas blocks must tile the padded array)."""
-    return max(align, min(pref, -(-dim // align) * align))
+_SUBLANE, _LANE = 8, 128
+
+
+def _clamp_block(pref: int, dim: int, align: int = _SUBLANE) -> int:
+    """Block along one axis of extent `dim`, preferring `pref`.
+
+    The block is `pref` rounded up to `align`, or the whole padded axis
+    (`dim` rounded up to 8) when that is no larger.  The TPU compiler
+    takes a block whose last two dims are each the whole array axis or a
+    multiple of (8, 128), so lane (last) axes pass align=128."""
+    full = -(-dim // _SUBLANE) * _SUBLANE
+    return min(full, -(-pref // align) * align)
+
+
+def fit_blocks(n_planes: int, rows: int, k: int, n: int, bm: int, bn: int,
+               bk: int) -> Tuple[int, int, int]:
+    """(bm, bn, bk) for one dispatched (rows, k) x (k, n) tile, clamped to
+    its geometry and legal for the TPU compiler.
+
+    With several input planes the x operand is laid out (M, P*K), so a
+    K block never spans its whole last axis and must be a multiple of 128:
+    K pads up to it with zero rows, which add nothing to the dp.  Every
+    choice is numerically identical (exact int32 accumulation +
+    elementwise epilogue)."""
+    if n_planes > 1:
+        bk = -(-min(bk, k) // _LANE) * _LANE
+    else:
+        bk = _clamp_block(bk, k, _LANE)
+    return _clamp_block(bm, rows), _clamp_block(bn, n, _LANE), bk
 
 
 # preferred block-size palette the schedule autotuner (repro.tuner) searches;
@@ -134,7 +158,7 @@ BN_PALETTE = (32, 64, 128, 256)
 BK_PALETTE = (128, 256, 512, 1024)
 
 
-def block_candidates(rows: int, k: int, n: int,
+def block_candidates(rows: int, k: int, n: int, n_planes: int = 1,
                      bms: Tuple[int, ...] = BM_PALETTE,
                      bns: Tuple[int, ...] = BN_PALETTE,
                      bks: Tuple[int, ...] = BK_PALETTE
@@ -142,19 +166,19 @@ def block_candidates(rows: int, k: int, n: int,
     """Deduplicated legal (bm, bn, bk) block choices for one dispatched
     tile of GEMM shape (rows, k) x (k, n).
 
-    Each palette entry is clamped to the tile geometry exactly like
-    `kernel_variant_for_tile` clamps its preferred blocks, so every
-    returned choice names a real compiled variant — and because the kernel
-    is numerically identical at any block size (exact int32 accumulation +
-    elementwise epilogue), choosing among them can never change a bit.
+    Each palette entry is fitted to the tile geometry and its `n_planes`
+    input planes by `fit_blocks`, exactly as `kernel_variant_for_tile`
+    fits its preferred blocks, so every returned choice names a real
+    compiled variant — and because the kernel is numerically identical at
+    any block size (exact int32 accumulation + elementwise epilogue),
+    choosing among them can never change a bit.
     The schedule autotuner enumerates this set per layer."""
     out: list = []
     seen = set()
     for bm in bms:
         for bn in bns:
             for bk in bks:
-                c = (_clamp_block(bm, rows), _clamp_block(bn, n),
-                     _clamp_block(bk, k))
+                c = fit_blocks(n_planes, rows, k, n, bm, bn, bk)
                 if c not in seen:
                     seen.add(c)
                     out.append(c)
@@ -163,7 +187,6 @@ def block_candidates(rows: int, k: int, n: int,
 
 def kernel_variant_for_tile(prec: KernelPrecision, rows: int, k: int, n: int,
                             *, bm: int = 256, bn: int = 256, bk: int = 512,
-                            interpret: bool = True,
                             fuse_adc: bool = True) -> Callable:
     """Kernel variant fitted to one dispatched tile's geometry.
 
@@ -173,26 +196,25 @@ def kernel_variant_for_tile(prec: KernelPrecision, rows: int, k: int, n: int,
         col-tile N.  Under a sharded schedule these are the *per-device*
         extents, so each device compiles blocks sized to its own tile
         instead of padding to the full-macro defaults.
-      bm, bn, bk: preferred (maximum) block sizes; clamped per dimension.
+      bm, bn, bk: preferred block sizes, fitted by `fit_blocks`.
     Returns:
-      The cached callable of `kernel_variant` at the clamped block sizes —
+      The cached callable of `kernel_variant` at the fitted block sizes —
       numerically identical at any block size (exact int32 accumulation +
-      elementwise epilogue), so geometry clamping never changes a bit.
+      elementwise epilogue), so geometry fitting never changes a bit.
     """
-    return kernel_variant(prec, bm=_clamp_block(bm, rows),
-                          bn=_clamp_block(bn, n), bk=_clamp_block(bk, k),
-                          interpret=interpret, fuse_adc=fuse_adc)
+    bm, bn, bk = fit_blocks(prec.n_planes, rows, k, n, bm, bn, bk)
+    return kernel_variant(prec, bm=bm, bn=bn, bk=bk, fuse_adc=fuse_adc)
 
 
 @functools.lru_cache(maxsize=None)
 def _kernel_variant(shift: int, n_planes: int, r_out: int, bm: int, bn: int,
-                    bk: int, interpret: bool, fuse_adc: bool) -> Callable:
+                    bk: int, fuse_adc: bool) -> Callable:
     r_eff = shift * n_planes          # widest r_in with this plane layout
 
     def run(x_q, w_q, gamma, beta, g0: float):
         return cim_matmul(x_q, w_q, gamma, beta, r_in=r_eff, r_out=r_out,
                           g0=g0, plane_shift=shift, bm=bm, bn=bn, bk=bk,
-                          interpret=interpret, fuse_adc=fuse_adc)
+                          fuse_adc=fuse_adc)
     run.plane_shift = shift
     run.n_planes = n_planes
     run.r_out = r_out
@@ -204,7 +226,7 @@ def cim_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, gamma: jnp.ndarray,
                beta: jnp.ndarray, *, r_in: int, r_out: int, g0: float,
                plane_shift: Optional[int] = None,
                bm: int = 256, bn: int = 256, bk: int = 512,
-               interpret: bool = True, fuse_adc: bool = True) -> jnp.ndarray:
+               fuse_adc: bool = True) -> jnp.ndarray:
     """One macro row-tile (K <= n_rows recommended): int inputs -> ADC codes.
 
     x_q: (M, K) unsigned ints < 2^r_in; w_q: (K, N) odd ints; gamma (N,);
@@ -237,15 +259,14 @@ def cim_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, gamma: jnp.ndarray,
 
     codes = cim_mbiw_matmul_planes(
         x_planes, w_q, gamma2, beta2, plane_shift=shift, g0=g0,
-        r_out=r_out, bm=bm, bn=bn, bk=bk, interpret=interpret,
-        fuse_adc=fuse_adc)
+        r_out=r_out, bm=bm, bn=bn, bk=bk, fuse_adc=fuse_adc)
     return codes[:m, :n]
 
 
 def cim_linear(x_q: jnp.ndarray, w_q: jnp.ndarray, gamma: jnp.ndarray,
                beta: jnp.ndarray, *, r_in: int, r_w: int, r_out: int,
-               cfg: CIMMacroConfig = DEFAULT_MACRO, adaptive_swing: bool = True,
-               interpret: bool = True) -> jnp.ndarray:
+               cfg: CIMMacroConfig = DEFAULT_MACRO, adaptive_swing: bool = True
+               ) -> jnp.ndarray:
     """Full layer: row-tiled kernel calls with per-tile ADC, digital
     partial-sum recombination in dp units (host side, like the chip).
 
@@ -268,7 +289,7 @@ def cim_linear(x_q: jnp.ndarray, w_q: jnp.ndarray, gamma: jnp.ndarray,
     for t in range(row_tiles):
         ks, ke = t * n_rows, min((t + 1) * n_rows, k_dim)
         codes = cim_matmul(x_q[:, ks:ke], w_q[ks:ke], gamma, beta,
-                           r_in=r_in, r_out=r_out, g0=g0, interpret=interpret)
+                           r_in=r_in, r_out=r_out, g0=g0)
         dp_hat += (codes.astype(jnp.float32) + 0.5 - mid - beta[None, :]) \
             / (gamma[None, :] * g0)
     return dp_hat

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.jax_compat import tpu_compiler_params
+from repro.kernels.platform import platform_call
 
 NEG_INF = -1e30
 
@@ -76,12 +76,12 @@ def _flash_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "sk_valid", "rep", "bq", "bk", "interpret"))
+    "causal", "window", "sk_valid", "rep", "bq", "bk"))
 def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          q_off: jnp.ndarray = None, *,
                          causal: bool, window: int = 0, sk_valid: int = 0,
-                         rep: int = 1, bq: int = 512, bk: int = 512,
-                         interpret: bool = True) -> jnp.ndarray:
+                         rep: int = 1, bq: int = 512,
+                         bk: int = 512) -> jnp.ndarray:
     """q (B, H, Sq, D); k/v (B, G, Sk, D) with H = G * rep; pre-padded to
     block multiples.  sk_valid masks KV padding (0 -> all valid).
     q_off: (1,1) int32 — global position of q row 0 (context parallelism:
@@ -100,7 +100,7 @@ def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         _flash_kernel, n_kv=n_kv, bq=bq, bk=bk, causal=causal,
         window=window, sk_valid=sk_valid, scale=scale)
     grid = (b, h, sq // bq, n_kv)
-    return pl.pallas_call(
+    return platform_call(lambda interpret: pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -121,10 +121,10 @@ def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, 1), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-    )(q_off, q, k, v)
+    ), q_off, q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -204,11 +204,10 @@ def _flash_bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "sk_valid", "rep", "bq", "bk", "interpret"))
+    "causal", "window", "sk_valid", "rep", "bq", "bk"))
 def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, q_off=None, *,
                              causal: bool, window: int = 0, sk_valid: int = 0,
-                             rep: int = 1, bq: int = 512, bk: int = 512,
-                             interpret: bool = True):
+                             rep: int = 1, bq: int = 512, bk: int = 512):
     if q_off is None:
         q_off = jnp.zeros((1, 1), jnp.int32)
     """Backward: q/do (B,H,Sq,D), k/v (B,G,Sk,D), lse/delta (B,H,Sq,1).
@@ -226,7 +225,7 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, q_off=None, *,
                            lambda b_, h_, i, j, rep=rep: (b_, h_ // rep, j, 0))
     stat_spec = pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0))
 
-    dq = pl.pallas_call(
+    dq = platform_call(lambda interpret: pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_kv=n_kv, bq=bq, bk=bk,
                           causal=causal, window=window, sk_valid=sk_valid,
                           scale=scale),
@@ -237,10 +236,10 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, q_off=None, *,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-    )(q_off, q, k, v, do, lse, delta)
+    ), q_off, q, k, v, do, lse, delta)
 
     # dk/dv: grid transposed, q innermost; outputs per q-head
     q_spec2 = pl.BlockSpec((1, 1, bq, d), lambda b_, h_, j, i: (b_, h_, i, 0))
@@ -250,7 +249,7 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, q_off=None, *,
     stat_spec2 = pl.BlockSpec((1, 1, bq, 1),
                               lambda b_, h_, j, i: (b_, h_, i, 0))
     off_spec2 = pl.BlockSpec((1, 1), lambda b_, h_, j, i: (0, 0))
-    dk, dv = pl.pallas_call(
+    dk, dv = platform_call(lambda interpret: pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, n_q=n_q, bq=bq, bk=bk,
                           causal=causal, window=window, sk_valid=sk_valid,
                           scale=scale),
@@ -263,8 +262,8 @@ def flash_attention_bwd_bhsd(q, k, v, do, lse, delta, q_off=None, *,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-    )(q_off, q, k, v, do, lse, delta)
+    ), q_off, q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -25,13 +25,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.experimental import pallas as pl
-from jax.sharding import PartitionSpec as P
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
-from repro.jax_compat import get_abstract_mesh, shard_map
 from repro.kernels.flash_attn.kernel import (flash_attention_bhsd,
                                              flash_attention_bwd_bhsd)
 from repro.kernels.flash_attn.ref import attention_ref
+from repro.kernels.platform import platform_call
 
 _FLOAT0 = jax.dtypes.float0
 
@@ -171,11 +172,29 @@ def flash_attention_sharded(q, k, v, causal: bool = True, window: int = 0,
 # expressed as a precomputed additive bias (slots the row has not written
 # yet sit out of positional order, so the index-generated causal/window
 # masks of `flash_attention` cannot describe them).  The whole working set
-# is tiny (R <= slot capacity, L = KV window), so the kernel holds it in
-# one VMEM-resident block — no online softmax, no KV grid — and performs
-# literally the op sequence of the digital reference, which keeps it
-# bit-exact with `ring_decode_attention_ref` (tests/test_scheduler.py
-# asserts equality, not closeness).
+# is small (R <= slot capacity, L = KV window), so the kernel holds it in
+# one VMEM block — no online softmax, no KV grid.  Rows and heads are
+# flattened into one leading batch axis (the batch layout Mosaic lowers),
+# and the digital oracle computes the same batched dots on the same
+# layout with plain einsums, which keeps the two bit-exact in the
+# interpreter (tests/test_scheduler.py asserts equality, not closeness).
+# Both dots ask for full f32 precision: on a TPU the default would round
+# the operands to bf16, and the two compilers need not round alike.
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+def _rows_heads_flat(q, k, v, bias):
+    """(R, H, hd), (R, L, H, hd) x2, (R, L) -> one (row, head) batch axis:
+    (R*H, 1, hd), (R*H, L, hd) x2, (R*H, 1, L)."""
+    r, h, hd = q.shape
+    n_l = k.shape[1]
+
+    def kv(a):
+        return jnp.swapaxes(a, 1, 2).reshape(r * h, n_l, hd)
+    b = jnp.broadcast_to(bias[:, None, :], (r, h, n_l))
+    return (q.reshape(r * h, 1, hd), kv(k), kv(v),
+            b.reshape(r * h, 1, n_l))
 
 
 @jax.jit
@@ -184,41 +203,45 @@ def ring_decode_attention_ref(q, k, v, bias) -> jnp.ndarray:
 
     q (R, H, hd); k/v (R, L, H, hd) — each row's own KV ring; bias (R, L)
     additive scores mask (0 for valid ring slots, -1e9 for unwritten).
-    Returns (R, H, hd).  The op sequence is exactly the digital path
-    CIMDecodeLM computed inline before the kernel existed; oracle and
-    kernel are both jitted as one unit so their graphs fuse identically
-    and the bit-exactness contract is equality, not closeness."""
-    hd = q.shape[-1]
-    scores = jnp.einsum("rhd,rlhd->rhl", q, k) / np.sqrt(hd)
-    probs = jax.nn.softmax(scores + bias[:, None, :], axis=-1)
-    return jnp.einsum("rhl,rlhd->rhd", probs, v)
+    Returns (R, H, hd).  Oracle and kernel are both jitted as one unit so
+    the bit-exactness contract is equality, not closeness."""
+    qf, kf, vf, bf = _rows_heads_flat(q, k, v, bias)
+    scores = jnp.einsum("bqd,bld->bql", qf, kf,
+                        precision=_HI) / np.sqrt(q.shape[-1])
+    probs = jax.nn.softmax(scores + bf, axis=-1)
+    out = jnp.einsum("bql,bld->bqd", probs, vf, precision=_HI)
+    return out.reshape(q.shape)
 
 
 def _ring_decode_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, *,
                         scale: float):
-    q = q_ref[...]
-    k = k_ref[...]
-    v = v_ref[...]
-    bias = b_ref[...]
-    scores = jnp.einsum("rhd,rlhd->rhl", q, k) / scale
-    probs = jax.nn.softmax(scores + bias[:, None, :], axis=-1)
-    o_ref[...] = jnp.einsum("rhl,rlhd->rhd", probs, v)
+    s = jax.lax.dot_general(q_ref[...], k_ref[...],
+                            (((2,), (2,)), ((0,), (0,))), precision=_HI,
+                            preferred_element_type=jnp.float32) / scale
+    probs = jax.nn.softmax(s + b_ref[...], axis=-1)
+    o_ref[...] = jax.lax.dot_general(
+        probs, v_ref[...], (((2,), (1,)), ((0,), (0,))), precision=_HI,
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 @jax.jit
 def ring_decode_attention(q, k, v, bias) -> jnp.ndarray:
     """Pallas ring-buffer decode attention (bit-exact with
-    `ring_decode_attention_ref`).
+    `ring_decode_attention_ref` in the interpreter).
 
     Same shapes as the ref: q (R, H, hd), k/v (R, L, H, hd), bias (R, L).
-    One pallas_call over the whole (VMEM-resident) decode working set;
-    the kernel body is the identical einsum/softmax/einsum sequence, so
-    interpretation executes the same graph and the outputs match the
-    digital path bit for bit."""
+    One pallas_call over the whole (VMEM-resident) decode working set."""
     scale = float(np.sqrt(q.shape[-1]))
-    with jax.named_scope("vmem_kernel"):
+    qf = _rows_heads_flat(q, k, v, bias)[0]
+
+    def build(interpret: bool):
         return pl.pallas_call(
             functools.partial(_ring_decode_kernel, scale=scale),
-            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-            interpret=True,
-        )(q, k, v, bias)
+            out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
+            interpret=interpret,
+            name="ring_decode_attention",
+        )
+
+    with jax.named_scope("vmem_kernel"):
+        out = platform_call(build, *_rows_heads_flat(q, k, v, bias))
+    return out.reshape(q.shape)

@@ -28,7 +28,6 @@ from repro.configs import all_archs, get_config
 from repro.configs.base import SHAPES, shape_applicable
 from repro.core.cim_layers import CIMConfig
 from repro.launch import hlo_analysis, specs
-from repro.jax_compat import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (init_train_state, make_prefill_step,
                                 make_serve_step, make_train_step)
@@ -100,7 +99,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *,
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     t0 = time.time()
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             inputs = specs.input_specs(cfg, shape)
             in_specs = specs.batch_specs(inputs, mesh)
             in_shard = jax.tree.map(lambda s: NamedSharding(mesh, s), in_specs)

@@ -6,8 +6,14 @@ jax device state (required by the dry-run's XLA_FLAGS ordering).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.jax_compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis of Auto type (sharding left to the
+    partitioner, which the models' constraint helpers assume)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

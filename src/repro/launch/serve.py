@@ -34,11 +34,16 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.cim_layers import CIMConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_serve_step
 from repro.models import transformer as tf
 
 
-def main():
+def main(argv=None):
+    """Parse `argv` (default: sys.argv) and serve.  The batched (non
+    --inflight) path returns what it served — config, params, prompt,
+    prefill logits, generated tokens, timings — so a caller in the same
+    process can check the results."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -71,8 +76,14 @@ def main():
                          "and serve per-request operating points through "
                          "the in-flight scheduler ('mixed' alternates "
                          "quality/throughput requests)")
+    ap.add_argument("--dtype", default=None,
+                    choices=["bfloat16", "float32"],
+                    help="activation dtype (default: the config's); the "
+                         "engine-mode logits equal fakequant's bit for bit "
+                         "at float32")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    print(f"compile cache: {enable_compile_cache()}")
 
     if args.precision_policy != "off":
         if args.cim_mode != "engine" or not args.inflight:
@@ -88,6 +99,8 @@ def main():
         sharding = ShardingConfig(devices=args.engine_devices,
                                   axis=args.engine_axis)
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
+    if args.dtype:
+        cfg = cfg.replace(dtype=args.dtype)
     cfg = cfg.replace(cim=CIMConfig(mode=args.cim_mode, max_gamma=2.0**16,
                                     sharding=sharding,
                                     isolate_rows=args.inflight))
@@ -95,6 +108,11 @@ def main():
     params = tf.init_params(cfg, key)
     if args.inflight:
         return _run_inflight(ap, args, cfg, params)
+    return _run_batched(args, cfg, params, key)
+
+
+def _run_batched(args, cfg, params, key):
+    """Batched prefill, then greedy decode of the whole batch per step."""
     max_len = args.prompt_len + args.gen_len + 8
     cache = tf.init_cache(cfg, args.batch, max_len=max_len)
 
@@ -112,7 +130,9 @@ def main():
     t0 = time.time()
     logits, cache, _ = tf.forward(cfg, params, prompt, cache=cache, **kwargs)
     tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-    print(f"prefill({prompt.shape[1]} tokens): {time.time()-t0:.2f}s")
+    tok.block_until_ready()
+    t_prefill = time.time() - t0
+    print(f"prefill({prompt.shape[1]} tokens): {t_prefill:.2f}s")
 
     serve_step = jax.jit(make_serve_step(cfg), donate_argnums=(1,))
     out = [tok]
@@ -154,6 +174,10 @@ def main():
             f"warmup (plans +{d_plans}, traces +{d_traces}) — the "
             f"plan-once/serve-many contract is broken")
     print("sample:", gen[0].tolist())
+    return {"cfg": cfg, "params": params, "prompt": prompt,
+            "prefill_logits": logits, "tokens": gen,
+            "prefill_s": t_prefill, "warmup_s": t_warm, "decode_s": dt,
+            "decode_steps": steps}
 
 
 def _run_inflight(ap, args, cfg, params):

@@ -21,6 +21,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.core.cim_layers import CIMConfig
 from repro.core.noise_model import NoiseConfig
 from repro.data.lm_data import LMDataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import init_train_state, make_train_step
 from repro.optim import AdamWConfig
 from repro.runtime.fault_tolerance import FTConfig, TrainDriver
@@ -66,6 +67,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=25)
     args = ap.parse_args()
 
+    print(f"compile cache: {enable_compile_cache()}")
     cfg, state, step_fn, batch_fn = build(args)
     n_params = sum(int(np.prod(p.shape))
                    for p in jax.tree.leaves(state["params"]))

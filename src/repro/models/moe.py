@@ -30,7 +30,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
 from repro.core import abn as abn_lib
 from repro.core import mapping
@@ -38,7 +39,6 @@ from repro.core import noise_model as nm
 from repro.core.cim_layers import CIMConfig, _code_gain, _engine_config
 from repro.core.quantization import (adc_quantize, quantize_act,
                                      quantize_weight, rounding_barrier)
-from repro.jax_compat import get_abstract_mesh, shard_map
 from repro.models.common import activation_fn
 from repro.models.sharding import BATCH, TP, mesh_spec, shard
 

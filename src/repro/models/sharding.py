@@ -10,9 +10,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Union
 
 import jax
-from jax.sharding import PartitionSpec as P
-
-from repro.jax_compat import get_abstract_mesh
+from jax.sharding import PartitionSpec as P, get_abstract_mesh
 
 # logical axis groups
 BATCH = ("pod", "data")     # pure data-parallel axes

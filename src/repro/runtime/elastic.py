@@ -15,7 +15,7 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.jax_compat import make_mesh as _compat_make_mesh
+from repro.launch.mesh import make_mesh as _auto_mesh
 
 
 def choose_mesh_shape(n_devices: int, tp: int = 16,
@@ -28,7 +28,7 @@ def choose_mesh_shape(n_devices: int, tp: int = 16,
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
-    return _compat_make_mesh(tuple(shape), tuple(axes))
+    return _auto_mesh(shape, axes)
 
 
 def reshard_tree(tree, shardings):

@@ -41,8 +41,8 @@ through one engine:
 Multi-macro sharding: the 1152x256 macro is a building block — the paper's
 system-level 40 TOPS/W numbers assume it is replicated.  With
 `EngineConfig(sharding=ShardingConfig(devices=D))` each layer's schedule
-partitions across a 1-D `jax.sharding.Mesh` of D devices (the
-`jax_compat.shard_map` shim; the per-device body is the same cached Pallas
+partitions across a 1-D `jax.sharding.Mesh` of D devices through
+`jax.shard_map` (the per-device body is the same cached Pallas
 variant): layers with at least D independent col tiles shard those
 (`mapping.shard_layer` kind "col", disjoint output channels per device);
 layers with fewer col tiles shard the GEMM-row dimension M = B*OH*OW via
@@ -183,7 +183,6 @@ class EngineConfig:
     adaptive_swing: bool = True      # serial-split DPL swing adaptation
     gamma_bits: int = -1             # -1: continuous gamma; >=0: HW quant
     max_gamma: float = 32.0
-    interpret: bool = True           # Pallas interpret mode (CPU) vs TPU
     bm: int = 128                    # kernel block sizes (MXU-aligned),
     bn: int = 128                    # clamped per dispatched tile geometry
     bk: int = 256
@@ -416,10 +415,15 @@ def plan_network(specs: Sequence[mapping.LayerSpec],
 
 def im2col_patches(x: jnp.ndarray, g: mapping.ConvGeometry) -> jnp.ndarray:
     """(B, H, W, C_in) -> (B, out_h, out_w, kh*kw*C_in) patch tensor whose
-    trailing axis matches the engine's (K, N) weight layout."""
+    trailing axis matches the engine's (K, N) weight layout.
+
+    The patches are a one-hot convolution; full precision makes it an
+    exact copy of the activations on a TPU too, where the default would
+    round them to bf16."""
     patches = jax.lax.conv_general_dilated_patches(
         x, (g.kh, g.kw), (g.stride, g.stride), padding=list(g.padding),
-        dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
     b, oh, ow, kf = patches.shape
     # conv_general_dilated_patches returns channel-major (C*kh*kw) features;
     # weights are laid out (kh*kw*C) — reorder to match (cf. cim_layers).
@@ -708,8 +712,6 @@ def _sharded_schedule(lp: LayerPlan, cfg: EngineConfig, q_rows: jnp.ndarray,
     single-device schedule (padding only ever adds discarded rows/cols)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.jax_compat import shard_map
-
     shard, m = lp.shard, q_rows.shape[0]
     mesh = _engine_mesh(cfg.sharding, shard.devices)
     ax = cfg.sharding.axis
@@ -734,8 +736,8 @@ def _sharded_schedule(lp: LayerPlan, cfg: EngineConfig, q_rows: jnp.ndarray,
                      nctx.gain_mult, _pad_dim(nctx.thermal, 1, t_tot)]
             specs += [P(ax), P(ax), P(), P(None, ax, None, None)]
 
-        out = shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                        out_specs=P(None, ax), check_vma=False)(*args)
+        out = jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                            out_specs=P(None, ax), check_vma=False)(*args)
         return out                       # (m, n_tot); caller slices cols
 
     # kind == "rows": data-parallel over the GEMM-row dimension; a per-row
@@ -752,8 +754,8 @@ def _sharded_schedule(lp: LayerPlan, cfg: EngineConfig, q_rows: jnp.ndarray,
                  _pad_dim(nctx.thermal, 2, m_tot)]
         specs += [P(), P(), P(), P(None, None, ax, None)]
 
-    out = shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                    out_specs=P(ax, None), check_vma=False)(*args)
+    out = jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                        out_specs=P(ax, None), check_vma=False)(*args)
     return out[:m]                       # drop row padding
 
 
@@ -866,8 +868,7 @@ def _kernel_matmul(lp: LayerPlan, cfg: EngineConfig):
         # full-macro padding
         fn = kops.kernel_variant_for_tile(
             lp.precision, xq.shape[0], xq.shape[1], wqt.shape[1],
-            bm=bm, bn=bn, bk=bk, interpret=cfg.interpret,
-            fuse_adc=fuse)
+            bm=bm, bn=bn, bk=bk, fuse_adc=fuse)
         return fn(xq, wqt, gamma_t, beta_t, g0)
     return matmul
 

@@ -53,11 +53,14 @@ def heuristic_choice(spec: mapping.LayerSpec, cfg,
     shard kind (shard_kind=None)."""
     mp = mapping.map_layer(spec, macro)
     tile_n = math.ceil(spec.n / mp.col_tiles)
-    return ScheduleChoice(
-        kops._clamp_block(getattr(cfg, "bm", 128), spec.m),
-        kops._clamp_block(getattr(cfg, "bn", 128), tile_n),
-        kops._clamp_block(getattr(cfg, "bk", 256), mp.rows_per_tile),
-        None)
+    return ScheduleChoice(*kops.fit_blocks(
+        _n_planes(spec), spec.m, mp.rows_per_tile, tile_n,
+        getattr(cfg, "bm", 128), getattr(cfg, "bn", 128),
+        getattr(cfg, "bk", 256)), None)
+
+
+def _n_planes(spec: mapping.LayerSpec) -> int:
+    return kops.KernelPrecision(spec.r_in, spec.r_w, spec.r_out).n_planes
 
 
 def layer_candidates(spec: mapping.LayerSpec, cfg, devices: int,
@@ -83,7 +86,7 @@ def layer_candidates(spec: mapping.LayerSpec, cfg, devices: int,
             rows_local = mapping.shard_layer(spec, mp, devices,
                                              kind=kind).rows_per_device
         for bm, bn, bk in kops.block_candidates(rows_local, mp.rows_per_tile,
-                                                tile_n):
+                                                tile_n, _n_planes(spec)):
             c = ScheduleChoice(bm, bn, bk, kind)
             if c not in seen:
                 seen.add(c)
@@ -92,7 +95,7 @@ def layer_candidates(spec: mapping.LayerSpec, cfg, devices: int,
 
 
 def _measure_choice_s(spec: mapping.LayerSpec, choice: ScheduleChoice,
-                      macro: CIMMacroConfig, interpret: bool) -> float:
+                      macro: CIMMacroConfig) -> float:
     """Wall-clock one candidate: run the real kernel on deterministic
     synthetic data for one (row tile, col tile) dispatch and take the min
     of a few repeats.  Used only for ranking — never for numerics."""
@@ -114,7 +117,7 @@ def _measure_choice_s(spec: mapping.LayerSpec, choice: ScheduleChoice,
             jax.numpy.asarray(x_q), jax.numpy.asarray(w_q),
             jax.numpy.asarray(gamma), jax.numpy.asarray(beta),
             r_in=spec.r_in, r_out=spec.r_out, g0=1.0,
-            bm=choice.bm, bn=choice.bn, bk=choice.bk, interpret=interpret)
+            bm=choice.bm, bn=choice.bn, bk=choice.bk)
         jax.block_until_ready(out)
 
     run()                              # compile outside the timed region
@@ -168,8 +171,7 @@ def tune_layer(spec: mapping.LayerSpec, cfg, devices: int, *,
     if mode == "measure":
         ranked = sorted(scored, key=lambda sc: sc[0].score())
         top = ranked[:MEASURE_TOP_K]
-        interpret = bool(getattr(cfg, "interpret", True))
-        timed = [(_measure_choice_s(spec, c, macro, interpret), lc, c)
+        timed = [(_measure_choice_s(spec, c, macro), lc, c)
                  for lc, c in top]
         _, best_cost, best = min(timed, key=lambda t: t[0])
 

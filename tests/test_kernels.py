@@ -1,4 +1,5 @@
-"""Per-kernel sweep: Pallas cim_mbiw vs the pure-jnp oracle (interpret=True)."""
+"""Per-kernel sweep: Pallas cim_mbiw vs the pure-jnp oracle (the kernel runs
+in the Pallas interpreter on CPU, compiled by Mosaic on a TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -116,3 +117,25 @@ def test_split_planes():
     np.testing.assert_array_equal(lo + 16 * hi, np.asarray(x))
     planes7, n7 = ops.split_planes(jnp.array([[127]], jnp.int32), 7)
     assert n7 == 1
+
+
+@pytest.mark.parametrize("r_in,rows,k,n", [
+    (8, 1024, 576, 256), (8, 16, 200, 64), (8, 802816, 9, 16),
+    (2, 4, 1152, 256), (4, 16, 144, 107), (1, 1, 2000, 320)])
+def test_fit_blocks_tpu_legal(r_in, rows, k, n):
+    """Every fitted block obeys the TPU rule: each of the last two block
+    dims is the whole (padded) array axis or a multiple of (8, 128).  With
+    several input planes x is (M, P*K), so bk can never span its last axis
+    and must be a multiple of 128 (the parent clamped it to 8)."""
+    prec = ops.KernelPrecision(r_in, 4, 8)
+    for pref in ((128, 128, 256), (32, 64, 1024), (256, 256, 512)):
+        bm, bn, bk = ops.fit_blocks(prec.n_planes, rows, k, n, *pref)
+        m_pad, n_pad = -(-rows // bm) * bm, -(-n // bn) * bn
+        k_pad = -(-k // bk) * bk
+        assert bm % 8 == 0
+        assert bn % 128 == 0 or bn == n_pad
+        if prec.n_planes > 1:
+            assert bk % 128 == 0
+        else:
+            assert bk % 128 == 0 or bk == k_pad
+        assert bm <= max(m_pad, 8) and k_pad - k < bk and n_pad - n < bn
