@@ -291,19 +291,23 @@ def _engine_forward(params: Dict, x: jnp.ndarray, cfg: CIMConfig,
     # isolated subgraph in an engine-mode and a fakequant-mode model, so
     # XLA fuses (and rounds) it identically in both — the stack-level
     # half of the bit-exactness contract (see _fakequant_forward)
-    x2 = rounding_barrier(x.reshape((-1, k_dim)))
+    with jax.named_scope("cim.act_quant"):
+        x2 = rounding_barrier(x.reshape((-1, k_dim)))
+        segments = None
+        if cfg.isolate_rows and lead:
+            # one segment per leading batch row: (B, S, K) -> B segments
+            # of S rows each, so fused rows quantize exactly as served
+            # alone
+            segments = jnp.repeat(jnp.arange(lead[0], dtype=jnp.int32),
+                                  x2.shape[0] // lead[0])
     bucket = DEFAULT_BUCKETS.bucket_for(x2.shape[0])
     spec = mapping.LayerSpec(m=bucket, k=k_dim, n=n, r_in=cfg.r_in,
                              r_w=cfg.r_w, r_out=cfg.r_out)
     prog = compile_program([spec], _engine_config(cfg))
-    segments = None
-    if cfg.isolate_rows and lead:
-        # one segment per leading batch row: (B, S, K) -> B segments of
-        # S rows each, so fused rows quantize exactly as served alone
-        segments = jnp.repeat(jnp.arange(lead[0], dtype=jnp.int32),
-                              x2.shape[0] // lead[0])
-    y = rounding_barrier(prog.serve([params], x2, key, segments=segments))
-    return y.reshape(lead + (n,)).astype(x.dtype)
+    y = prog.serve([params], x2, key, segments=segments)
+    with jax.named_scope("cim.epilogue"):
+        y = rounding_barrier(y)
+        return y.reshape(lead + (n,)).astype(x.dtype)
 
 
 def _sim_forward(params: Dict, x: jnp.ndarray, cfg: CIMConfig,

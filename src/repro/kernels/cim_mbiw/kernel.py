@@ -123,7 +123,8 @@ def cim_mbiw_matmul_planes(x_planes: jnp.ndarray, w_q: jnp.ndarray,
     n_k_total = n_planes * n_k_inner
     # the ADC gain, computed (and pinned) outside the kernel exactly as
     # ref.py computes it
-    gain = jax.lax.optimization_barrier(gamma * g0)
+    with jax.named_scope("cim.planes"):
+        gain = jax.lax.optimization_barrier(gamma * g0)
 
     beta_spec = (pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
                  if beta.shape[0] == m and m != 1 else
@@ -152,4 +153,5 @@ def cim_mbiw_matmul_planes(x_planes: jnp.ndarray, w_q: jnp.ndarray,
             name="cim_mbiw",
         )
 
-    return platform_call(build, x_planes, w_q, gain, beta)
+    with jax.named_scope("cim.kernel"):
+        return platform_call(build, x_planes, w_q, gain, beta)

@@ -29,6 +29,7 @@ import dataclasses
 import functools
 from typing import Callable, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import digital_ref
@@ -236,31 +237,34 @@ def cim_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, gamma: jnp.ndarray,
     """
     m, k_dim = x_q.shape
     _, n = w_q.shape
-    x_planes, n_planes = split_planes(x_q, r_in, plane_shift)
     shift = _PLANE_SHIFT if plane_shift is None else plane_shift
-
-    # pad: K to bk multiple (per-plane), M to bm, N to bn.  Padding K with
-    # zero inputs/weights adds 0 to the dp — same trick the macro uses when
-    # a layer does not fill its 36-row units.
-    k_pad = (-k_dim) % bk
-    if k_pad:
-        xp = x_planes.reshape(m, n_planes, k_dim)
-        xp = jnp.pad(xp, ((0, 0), (0, 0), (0, k_pad)))
-        x_planes = xp.reshape(m, n_planes * (k_dim + k_pad))
-        w_q = jnp.pad(w_q, ((0, k_pad), (0, 0)))
-    x_planes = _pad_to(x_planes, (bm, 1))
-    w_q = _pad_to(w_q.astype(jnp.int8), (1, bn))
-    gamma2 = _pad_to(gamma.reshape(1, -1).astype(jnp.float32), (1, bn))
-    if beta.ndim == 2 and beta.shape[0] == m and m != 1:
-        # per-row offset: pad rows in lockstep with x (pad rows discarded)
-        beta2 = _pad_to(beta.astype(jnp.float32), (bm, bn))
-    else:
-        beta2 = _pad_to(beta.reshape(1, -1).astype(jnp.float32), (1, bn))
+    with jax.named_scope("cim.planes"):
+        x_planes, n_planes = split_planes(x_q, r_in, plane_shift)
+        # pad: K to bk multiple (per-plane), M to bm, N to bn.  Padding K
+        # with zero inputs/weights adds 0 to the dp — same trick the macro
+        # uses when a layer does not fill its 36-row units.
+        k_pad = (-k_dim) % bk
+        if k_pad:
+            xp = x_planes.reshape(m, n_planes, k_dim)
+            xp = jnp.pad(xp, ((0, 0), (0, 0), (0, k_pad)))
+            x_planes = xp.reshape(m, n_planes * (k_dim + k_pad))
+            w_q = jnp.pad(w_q, ((0, k_pad), (0, 0)))
+        x_planes = _pad_to(x_planes, (bm, 1))
+        w_q = _pad_to(w_q.astype(jnp.int8), (1, bn))
+        gamma2 = _pad_to(gamma.reshape(1, -1).astype(jnp.float32), (1, bn))
+        if beta.ndim == 2 and beta.shape[0] == m and m != 1:
+            # per-row offset: pad rows in lockstep with x (pad rows
+            # discarded)
+            beta2 = _pad_to(beta.astype(jnp.float32), (bm, bn))
+        else:
+            beta2 = _pad_to(beta.reshape(1, -1).astype(jnp.float32),
+                            (1, bn))
 
     codes = cim_mbiw_matmul_planes(
         x_planes, w_q, gamma2, beta2, plane_shift=shift, g0=g0,
         r_out=r_out, bm=bm, bn=bn, bk=bk, fuse_adc=fuse_adc)
-    return codes[:m, :n]
+    with jax.named_scope("cim.recombine"):
+        return codes[:m, :n]
 
 
 def cim_linear(x_q: jnp.ndarray, w_q: jnp.ndarray, gamma: jnp.ndarray,

@@ -90,7 +90,8 @@ def make_serve_step(cfg: ModelConfig, *, greedy: bool = True):
 
     def serve_step(params, cache, tokens):
         logits, new_cache, _ = tf.forward(cfg, params, tokens, cache=cache)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        with jax.named_scope("lm.head"):
+            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
         return nxt[:, None], new_cache
 
     return serve_step

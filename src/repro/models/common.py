@@ -40,19 +40,21 @@ def apply_norm(params: Dict, x: jnp.ndarray, kind: str,
                eps: float = 1e-6) -> jnp.ndarray:
     """Normalize the trailing feature axis in float32, cast back to
     x.dtype.  `kind` matches init_norm."""
-    xf = x.astype(jnp.float32)
-    if kind == "rmsnorm":
-        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-        y = y * params["scale"]
-    elif kind in ("layernorm", "nonparam_ln"):
-        mu = jnp.mean(xf, -1, keepdims=True)
-        var = jnp.var(xf, -1, keepdims=True)
-        y = (xf - mu) * jax.lax.rsqrt(var + eps)
-        if kind == "layernorm":
-            y = y * params["scale"] + params["bias"]
-    else:
-        raise ValueError(kind)
-    return y.astype(x.dtype)
+    with jax.named_scope("lm.norm"):
+        xf = x.astype(jnp.float32)
+        if kind == "rmsnorm":
+            y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
+                                   + eps)
+            y = y * params["scale"]
+        elif kind in ("layernorm", "nonparam_ln"):
+            mu = jnp.mean(xf, -1, keepdims=True)
+            var = jnp.var(xf, -1, keepdims=True)
+            y = (xf - mu) * jax.lax.rsqrt(var + eps)
+            if kind == "layernorm":
+                y = y * params["scale"] + params["bias"]
+        else:
+            raise ValueError(kind)
+        return y.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -236,126 +238,131 @@ def attention_block(params: Dict, x: jnp.ndarray, cfg: AttnConfig,
 
     The self-attention decode cache is a *ring buffer* of length L: writes
     land at idx % L, so sliding-window layers keep only their window."""
-    b, s, d = x.shape
-    h, g, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    src = x if x_kv is None else x_kv
-    kq = kk_key = kv_key = ko = None
-    if key is not None:
-        kq, kk_key, kv_key, ko = (jax.random.fold_in(key, i)
-                                  for i in range(4))
+    with jax.named_scope("lm.attention"):
+        b, s, d = x.shape
+        h, g, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        src = x if x_kv is None else x_kv
+        kq = kk_key = kv_key = ko = None
+        if key is not None:
+            kq, kk_key, kv_key, ko = (jax.random.fold_in(key, i)
+                                      for i in range(4))
 
-    use_pallas = (cfg.impl == "pallas" and s > 1 and cache is None
-                  and cross_kv is None)
-    q = cim_linear_apply(params["wq"], x, cim, key=kq)
-    if "bq" in params:
-        q = q + params["bq"]
-    q = q.reshape(b, s, h, hd)
-    if not use_pallas:
-        # pallas path: the kernel's shard_map in_specs define the layout;
-        # an extra constraint here only inserts reshard copies
-        q = shard(q, BATCH, None, TP, None)
+        use_pallas = (cfg.impl == "pallas" and s > 1 and cache is None
+                      and cross_kv is None)
+        q = cim_linear_apply(params["wq"], x, cim, key=kq)
+        if "bq" in params:
+            q = q + params["bq"]
+        q = q.reshape(b, s, h, hd)
+        if not use_pallas:
+            # pallas path: the kernel's shard_map in_specs define the layout;
+            # an extra constraint here only inserts reshard copies
+            q = shard(q, BATCH, None, TP, None)
 
-    if cross_kv is not None:
-        # cross-attention decode: encoder KV precomputed at prefill
-        k, v = cross_kv["k"], cross_kv["v"]
-        k_pos = jnp.arange(k.shape[1])
-        new_cache = cross_kv
-    else:
-        kk = cim_linear_apply(params["wk"], src, cim, key=kk_key)
-        vv = cim_linear_apply(params["wv"], src, cim, key=kv_key)
-        if "bk" in params:
-            kk, vv = kk + params["bk"], vv + params["bv"]
-        k = kk.reshape(b, src.shape[1], g, hd)
-        v = vv.reshape(b, src.shape[1], g, hd)
-        src_pos = positions if x_kv is None else (
-            kv_positions if kv_positions is not None
-            else jnp.arange(src.shape[1]))
-        if cfg.use_rope and x_kv is None:
-            inv = rope_frequencies(hd, cfg.rope_theta)
-            q = apply_rope(q, positions, inv)
-            k = apply_rope(k, src_pos, inv)
-        if kv_repeat_to:
-            k = _repeat_kv_to(k, kv_repeat_to)
-            v = _repeat_kv_to(v, kv_repeat_to)
-        if use_pallas:
-            pass  # shard_map in_specs drive k/v layout (replicated on TP)
-        if cache is not None and x_kv is None and cache["idx"].ndim == 1:
-            # slot-mapped decode (in-flight batching): `idx` is a (B,)
-            # per-slot write cursor, every batch row rides its own ring
-            # position.  Scatter-write one token per row; the mask
-            # positions become per-row (B, L) and plain_attention builds
-            # the keep-mask per batch row.
-            if s != 1:
-                raise ValueError(
-                    f"slot-mapped KV decode is single-token (s=1), got "
-                    f"s={s}; prefill per request and scatter into the "
-                    "slot with write_slot_kv")
-            length = cache["k"].shape[1]
-            idx = cache["idx"]
-            write = jax.lax.rem(idx, length)
-            rows = jnp.arange(b)
-            k = cache["k"].at[rows, write].set(
-                k[:, 0].astype(cache["k"].dtype))
-            v = cache["v"].at[rows, write].set(
-                v[:, 0].astype(cache["v"].dtype))
-            k = shard(k, BATCH, TP, None, None)
-            v = shard(v, BATCH, TP, None, None)
-            new_cache = {"k": k, "v": v, "idx": idx + s}
-            # position held by ring slot j after the write, per batch row
-            j = jnp.arange(length)[None, :]
-            last = (idx + s - 1)[:, None]
-            src_pos = last - jnp.mod(last - j, length)
-            src_pos = jnp.where(src_pos >= 0, src_pos, -10**9)
-        elif cache is not None and x_kv is None:
-            # decode: ring-buffer append at idx % L (s == 1 for decode;
-            # multi-token prefill-into-cache requires idx + s <= L)
-            length = cache["k"].shape[1]
-            idx = cache["idx"]
-            write = jax.lax.rem(idx, length)
-            k = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, write, 0, 0))
-            v = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, write, 0, 0))
-            k = shard(k, BATCH, TP, None, None)
-            v = shard(v, BATCH, TP, None, None)
-            new_cache = {"k": k, "v": v, "idx": idx + s}
-            # position held by ring slot j after the write
-            j = jnp.arange(length)
-            last = idx + s - 1
-            src_pos = last - jnp.mod(last - j, length)
-            src_pos = jnp.where(src_pos >= 0, src_pos, -10**9)
-        elif cache is not None:
-            new_cache = {"k": k, "v": v}
+        if cross_kv is not None:
+            # cross-attention decode: encoder KV precomputed at prefill
+            k, v = cross_kv["k"], cross_kv["v"]
+            k_pos = jnp.arange(k.shape[1])
+            new_cache = cross_kv
         else:
-            new_cache = None
-        if (cache is None or x_kv is not None) and not use_pallas:
-            k = shard(k, BATCH, None, TP, None)
-            v = shard(v, BATCH, None, TP, None)
-        k_pos = src_pos
+            kk = cim_linear_apply(params["wk"], src, cim, key=kk_key)
+            vv = cim_linear_apply(params["wv"], src, cim, key=kv_key)
+            if "bk" in params:
+                kk, vv = kk + params["bk"], vv + params["bv"]
+            k = kk.reshape(b, src.shape[1], g, hd)
+            v = vv.reshape(b, src.shape[1], g, hd)
+            src_pos = positions if x_kv is None else (
+                kv_positions if kv_positions is not None
+                else jnp.arange(src.shape[1]))
+            if cfg.use_rope and x_kv is None:
+                inv = rope_frequencies(hd, cfg.rope_theta)
+                q = apply_rope(q, positions, inv)
+                k = apply_rope(k, src_pos, inv)
+            if kv_repeat_to:
+                k = _repeat_kv_to(k, kv_repeat_to)
+                v = _repeat_kv_to(v, kv_repeat_to)
+            if use_pallas:
+                pass  # shard_map in_specs drive k/v layout (replicated on TP)
+            if cache is not None and x_kv is None and cache["idx"].ndim == 1:
+                # slot-mapped decode (in-flight batching): `idx` is a (B,)
+                # per-slot write cursor, every batch row rides its own ring
+                # position.  Scatter-write one token per row; the mask
+                # positions become per-row (B, L) and plain_attention builds
+                # the keep-mask per batch row.
+                if s != 1:
+                    raise ValueError(
+                        f"slot-mapped KV decode is single-token (s=1), got "
+                        f"s={s}; prefill per request and scatter into the "
+                        "slot with write_slot_kv")
+                length = cache["k"].shape[1]
+                idx = cache["idx"]
+                with jax.named_scope("lm.kv_write"):
+                    write = jax.lax.rem(idx, length)
+                    rows = jnp.arange(b)
+                    k = cache["k"].at[rows, write].set(
+                        k[:, 0].astype(cache["k"].dtype))
+                    v = cache["v"].at[rows, write].set(
+                        v[:, 0].astype(cache["v"].dtype))
+                    k = shard(k, BATCH, TP, None, None)
+                    v = shard(v, BATCH, TP, None, None)
+                new_cache = {"k": k, "v": v, "idx": idx + s}
+                # position held by ring slot j after the write, per batch row
+                j = jnp.arange(length)[None, :]
+                last = (idx + s - 1)[:, None]
+                src_pos = last - jnp.mod(last - j, length)
+                src_pos = jnp.where(src_pos >= 0, src_pos, -10**9)
+            elif cache is not None and x_kv is None:
+                # decode: ring-buffer append at idx % L (s == 1 for decode;
+                # multi-token prefill-into-cache requires idx + s <= L)
+                length = cache["k"].shape[1]
+                idx = cache["idx"]
+                with jax.named_scope("lm.kv_write"):
+                    write = jax.lax.rem(idx, length)
+                    k = jax.lax.dynamic_update_slice(
+                        cache["k"], k.astype(cache["k"].dtype),
+                        (0, write, 0, 0))
+                    v = jax.lax.dynamic_update_slice(
+                        cache["v"], v.astype(cache["v"].dtype),
+                        (0, write, 0, 0))
+                    k = shard(k, BATCH, TP, None, None)
+                    v = shard(v, BATCH, TP, None, None)
+                new_cache = {"k": k, "v": v, "idx": idx + s}
+                # position held by ring slot j after the write
+                j = jnp.arange(length)
+                last = idx + s - 1
+                src_pos = last - jnp.mod(last - j, length)
+                src_pos = jnp.where(src_pos >= 0, src_pos, -10**9)
+            elif cache is not None:
+                new_cache = {"k": k, "v": v}
+            else:
+                new_cache = None
+            if (cache is None or x_kv is not None) and not use_pallas:
+                k = shard(k, BATCH, None, TP, None)
+                v = shard(v, BATCH, None, TP, None)
+            k_pos = src_pos
 
-    # per-slot decode keeps 2D (B, S) q positions so the per-row masks of
-    # plain_attention line up; otherwise 2D positions collapse to row 0
-    # (shared across the batch, the pre-slot contract)
-    per_row = getattr(k_pos, "ndim", 1) == 2
-    q_pos = positions if (positions.ndim == 1 or per_row) else positions[0]
-    if use_pallas:
-        # fused VMEM flash kernel (fwd + bwd); positions are contiguous
-        # 0..S-1 in the no-cache path, masks generated in-kernel
-        from repro.kernels.flash_attn.ops import flash_attention_sharded
-        out = flash_attention_sharded(
-            q, k, v, cfg.causal and x_kv is None and s > 1,
-            cfg.window if x_kv is None else 0)
-    elif k.shape[1] > cfg.flash_threshold and s > 1:
-        out = flash_attention(q, k, v, q_pos=q_pos, k_pos=k_pos,
-                              causal=cfg.causal and x_kv is None,
-                              window=cfg.window)
-    else:
-        out = plain_attention(q, k, v, q_pos=q_pos, k_pos=k_pos,
-                              causal=cfg.causal and x_kv is None and s > 1,
-                              window=cfg.window if x_kv is None else 0)
-    out = out.reshape(b, s, h * hd)
-    y = cim_linear_apply(params["wo"], out, cim, key=ko)
-    return shard(y, BATCH, None, None), new_cache
+        # per-slot decode keeps 2D (B, S) q positions so the per-row masks of
+        # plain_attention line up; otherwise 2D positions collapse to row 0
+        # (shared across the batch, the pre-slot contract)
+        per_row = getattr(k_pos, "ndim", 1) == 2
+        q_pos = positions if (positions.ndim == 1 or per_row) else positions[0]
+        if use_pallas:
+            # fused VMEM flash kernel (fwd + bwd); positions are contiguous
+            # 0..S-1 in the no-cache path, masks generated in-kernel
+            from repro.kernels.flash_attn.ops import flash_attention_sharded
+            out = flash_attention_sharded(
+                q, k, v, cfg.causal and x_kv is None and s > 1,
+                cfg.window if x_kv is None else 0)
+        elif k.shape[1] > cfg.flash_threshold and s > 1:
+            out = flash_attention(q, k, v, q_pos=q_pos, k_pos=k_pos,
+                                  causal=cfg.causal and x_kv is None,
+                                  window=cfg.window)
+        else:
+            out = plain_attention(q, k, v, q_pos=q_pos, k_pos=k_pos,
+                                  causal=cfg.causal and x_kv is None and s > 1,
+                                  window=cfg.window if x_kv is None else 0)
+        out = out.reshape(b, s, h * hd)
+        y = cim_linear_apply(params["wo"], out, cim, key=ko)
+        return shard(y, BATCH, None, None), new_cache
 
 
 def init_kv_cache(batch: int, max_len: int, n_kv: int, head_dim: int,

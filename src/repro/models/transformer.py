@@ -258,8 +258,9 @@ def _scan_stack(layer_fn, stacked_params, x, cache, remat: bool,
     def body(carry, xs):
         x, aux = carry
         p, c = xs
-        new_x, new_c, a = fn(p, x, c)
-        return (new_x.astype(x.dtype), aux + a), new_c
+        with jax.named_scope("lm.layer"):
+            new_x, new_c, a = fn(p, x, c)
+            return (new_x.astype(x.dtype), aux + a), new_c
 
     (x, aux), new_cache = jax.lax.scan(
         body, (x, jnp.float32(0.0)), (stacked_params, cache))
@@ -325,25 +326,27 @@ def _hybrid_stack(cfg: ModelConfig, params, x, positions, cache):
 def embed_tokens(cfg: ModelConfig, params, tokens: jnp.ndarray) -> jnp.ndarray:
     """Token-id lookup into the (sharded) embedding table, cast to the
     model compute dtype."""
-    emb = shard(params["embed"], TP, None)
-    x = emb[tokens].astype(_dtype(cfg))
-    return shard(x, BATCH, None, None)
+    with jax.named_scope("lm.embed"):
+        emb = shard(params["embed"], TP, None)
+        x = emb[tokens].astype(_dtype(cfg))
+        return shard(x, BATCH, None, None)
 
 
 def lm_logits(cfg: ModelConfig, params, x: jnp.ndarray) -> jnp.ndarray:
     """Final norm + LM head (tied embedding, bypass-mode lm_head, or
     deploy-quantized serving weights — always digital, see DESIGN.md)."""
     x = cm.apply_norm(params["final_norm"], x, cfg.norm_type)
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"].T.astype(x.dtype)
-    elif "w" in params["lm_head"]:
-        # lm_head stays in bypass mode (DESIGN.md: quality-critical layer)
-        logits = x @ params["lm_head"]["w"].astype(x.dtype)
-    else:   # deploy-quantized serving weights
-        head = params["lm_head"]
-        logits = x @ (head["w_q"].astype(x.dtype)
-                      * head["w_scale"].astype(x.dtype))
-    return shard(logits, BATCH, None, TP)
+    with jax.named_scope("lm.head"):
+        if cfg.tie_embeddings:
+            logits = x @ params["embed"].T.astype(x.dtype)
+        elif "w" in params["lm_head"]:
+            # lm_head stays in bypass mode (DESIGN.md: quality-critical)
+            logits = x @ params["lm_head"]["w"].astype(x.dtype)
+        else:   # deploy-quantized serving weights
+            head = params["lm_head"]
+            logits = x @ (head["w_q"].astype(x.dtype)
+                          * head["w_scale"].astype(x.dtype))
+        return shard(logits, BATCH, None, TP)
 
 
 def forward(cfg: ModelConfig, params, tokens: jnp.ndarray, *,

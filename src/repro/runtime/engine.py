@@ -92,6 +92,34 @@ sigma/offset/gain terms enter the jitted schedule as *traced* scalars
 (NoiseConfig is a JAX pytree), so a sweep across noise operating points
 shares one compile: `engine(params, x, key, noise=point_i)`.
 
+Device scopes: every op of the hot path is traced inside one
+`jax.named_scope` of a flat taxonomy, so a profiler trace can charge each
+device op to the program layer that issued it (its `op_name` metadata
+carries the scope; an op belongs to the innermost taxonomy scope there):
+
+  cim.bind        weight quantization, ABN gamma, col-tile padding
+                  (bind_layer; inside the executable only when unbound)
+  cim.act_quant   pad-row pinning, activation quantization, zero-point,
+                  bucket padding, a projection's entry reshape
+  cim.im2col      im2col patches, reshape to GEMM rows, id repeats
+  cim.zp_fold     per-tile weight column sums, beta_eff
+  cim.planes      plane split, K/row/col padding, kernel operand slices,
+                  the ADC gain pin before the pallas_call
+  cim.kernel      the pallas_call (HLO name `cim_mbiw`)
+  cim.recombine   dequant/accumulate of codes, tile and chunk concat
+  cim.epilogue    scale multiply, activation, max-pool, reshape back
+  cim.noise       noise fields and the noisy ADC epilogue
+  lm.embed, lm.norm, lm.attention (RoPE, scores, softmax, PV),
+  lm.kv_write (the KV cache update), lm.head (logits, argmax),
+  lm.layer (the rest of a decoder layer: residuals, SwiGLU glue)
+
+The `lm.*` scopes live in models/common.py, models/transformer.py and
+launch/steps.py.  A scope is compile-time metadata: it changes no
+computation, no trace count and no compile, and is always on.  Renaming
+one renames a benchmark reading (bench/scopes.py sums scopes by these
+names).  `CIMProgram`'s bucketed dispatch runs inside the host span
+`repro.serve` (runtime/program.py).
+
 Units cheat-sheet (see also core/noise_model.py):
   * `dp` / `dp_hat`            — integer dot-product units (codes of the
                                   ideal digital MAC, pre-ADC);
@@ -463,17 +491,18 @@ def bind_layer(lp: LayerPlan, params: Dict[str, jnp.ndarray],
       with 1.0 — it divides in the dequant).
     """
     from repro.core.quantization import quantize_weight
-    wq = quantize_weight(params["w"], lp.spec.r_w, axis=0)
-    gamma = abn_lib.abn_gamma(
-        abn_lib.ABNParams(params["abn_log_gamma"], params["abn_beta"]),
-        gamma_bits=cfg.gamma_bits, max_gamma=cfg.max_gamma)
-    n_pad = lp.n_pad
-    return {
-        "wqq": _pad_dim(wq.q, 1, n_pad),
-        "w_scale": wq.scale.reshape(-1),
-        "gamma_p": _pad_dim(gamma, 0, n_pad, value=1.0),
-        "beta_p": _pad_dim(params["abn_beta"], 0, n_pad),
-    }
+    with jax.named_scope("cim.bind"):
+        wq = quantize_weight(params["w"], lp.spec.r_w, axis=0)
+        gamma = abn_lib.abn_gamma(
+            abn_lib.ABNParams(params["abn_log_gamma"], params["abn_beta"]),
+            gamma_bits=cfg.gamma_bits, max_gamma=cfg.max_gamma)
+        n_pad = lp.n_pad
+        return {
+            "wqq": _pad_dim(wq.q, 1, n_pad),
+            "w_scale": wq.scale.reshape(-1),
+            "gamma_p": _pad_dim(gamma, 0, n_pad, value=1.0),
+            "beta_p": _pad_dim(params["abn_beta"], 0, n_pad),
+        }
 
 
 def bind_network(plan: NetworkPlan, params: Params) -> Tuple[Dict, ...]:
@@ -638,35 +667,45 @@ def _tile_schedule(lp: LayerPlan, q_rows: jnp.ndarray, zp: jnp.ndarray,
     mid = 2.0 ** (lp.spec.r_out - 1)
     g0 = lp.g0
     tsz = lp.tile_n
-    # materialized ADC gain: the fakequant reference and this schedule must
-    # dequantize with the identical float in every fusion context
-    # (quantization.rounding_barrier)
-    gain = rounding_barrier(gamma * g0)
+    with jax.named_scope("cim.recombine"):
+        # materialized ADC gain: the fakequant reference and this schedule
+        # must dequantize with the identical float in every fusion context
+        # (quantization.rounding_barrier)
+        gain = rounding_barrier(gamma * g0)
     dp_hat = []
     for ni in range(wqq.shape[1] // tsz):
         ns, ne = ni * tsz, (ni + 1) * tsz
-        acc = jnp.zeros((q_rows.shape[0], tsz), jnp.float32)
+        with jax.named_scope("cim.recombine"):
+            acc = jnp.zeros((q_rows.shape[0], tsz), jnp.float32)
         for ki, (ks, ksz) in enumerate(lp.k_slices):
             ke = ks + ksz
-            # zero-point: x = q*s + z -> z*colsum is per-channel constant,
-            # folded into the ABN offset inside the ADC floor
-            zp_dp = zp * jnp.sum(wqq[ks:ke, ns:ne], axis=0)
-            beta_eff = beta[ns:ne] + rounding_barrier(gain[ns:ne] * zp_dp)
-            out = matmul(q_rows[:, ks:ke], wqq[ks:ke, ns:ne],
-                         gamma[ns:ne], beta_eff, g0)
+            with jax.named_scope("cim.zp_fold"):
+                # zero-point: x = q*s + z -> z*colsum is per-channel
+                # constant, folded into the ABN offset inside the ADC floor
+                zp_dp = zp * jnp.sum(wqq[ks:ke, ns:ne], axis=0)
+                beta_eff = beta[ns:ne] + rounding_barrier(gain[ns:ne]
+                                                          * zp_dp)
+            with jax.named_scope("cim.planes"):
+                # the kernel's own scopes (cim.kernel) are innermost
+                out = matmul(q_rows[:, ks:ke], wqq[ks:ke, ns:ne],
+                             gamma[ns:ne], beta_eff, g0)
             if nctx is None:
                 codes = out
             else:
-                codes = _noise_adc_code(lp, out, gamma[ns:ne], beta_eff,
-                                        nctx, (ns, ne),
-                                        nctx.thermal[ki, ni])
-            # digital partial-sum recombination in dp units; dequantizing
-            # against the *raw* beta keeps the zero-point contribution in
-            # dp_hat, exactly like the fakequant training path
-            acc = acc + (codes.astype(jnp.float32) + 0.5 - mid
-                         - beta[None, ns:ne]) / gain[None, ns:ne]
+                with jax.named_scope("cim.noise"):
+                    codes = _noise_adc_code(lp, out, gamma[ns:ne], beta_eff,
+                                            nctx, (ns, ne),
+                                            nctx.thermal[ki, ni])
+            with jax.named_scope("cim.recombine"):
+                # digital partial-sum recombination in dp units;
+                # dequantizing against the *raw* beta keeps the zero-point
+                # contribution in dp_hat, exactly like the fakequant
+                # training path
+                acc = acc + (codes.astype(jnp.float32) + 0.5 - mid
+                             - beta[None, ns:ne]) / gain[None, ns:ne]
         dp_hat.append(acc)
-    return jnp.concatenate(dp_hat, axis=-1)
+    with jax.named_scope("cim.recombine"):
+        return jnp.concatenate(dp_hat, axis=-1)
 
 
 def _schedule_rows(lp: LayerPlan, cfg: EngineConfig, q_rows: jnp.ndarray,
@@ -683,11 +722,15 @@ def _schedule_rows(lp: LayerPlan, cfg: EngineConfig, q_rows: jnp.ndarray,
     parts = []
     for s in range(0, max(m, 1), chunk):
         sl = slice(s, min(s + chunk, m))
+        with jax.named_scope("cim.noise"):
+            rows_nctx = nctx.rows(sl) if nctx is not None else None
         parts.append(_tile_schedule(
             lp, q_rows[sl], zp if zp.ndim == 0 else zp[sl], wqq, gamma,
-            beta, matmul=matmul,
-            nctx=nctx.rows(sl) if nctx is not None else None))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 0)
+            beta, matmul=matmul, nctx=rows_nctx))
+    if len(parts) == 1:
+        return parts[0]
+    with jax.named_scope("cim.recombine"):
+        return jnp.concatenate(parts, 0)
 
 
 def _engine_mesh(sharding: ShardingConfig, devices: int):
@@ -782,30 +825,33 @@ def _layer_tiles(lp: LayerPlan, bind: Dict[str, jnp.ndarray],
     `nid_rows`/`sub_rows` key the noise model's thermal draws by row
     identity instead of position (see _layer_noise)."""
     from repro.core.quantization import quantize_act
-    if seg_rows is None:
-        aq = quantize_act(x2, lp.spec.r_in)
-    else:
-        aq = quantize_act(x2, lp.spec.r_in, segment_ids=seg_rows,
-                          num_segments=x2.shape[0])
+    with jax.named_scope("cim.act_quant"):
+        if seg_rows is None:
+            aq = quantize_act(x2, lp.spec.r_in)
+        else:
+            aq = quantize_act(x2, lp.spec.r_in, segment_ids=seg_rows,
+                              num_segments=x2.shape[0])
+        zp = jnp.asarray(aq.zero / aq.scale, jnp.float32)
     n = lp.spec.n
     wqq, gamma_p, beta_p = bind["wqq"], bind["gamma_p"], bind["beta_p"]
     m = x2.shape[0]
-    nctx = (_layer_noise(lp, cfg, noise, gamma_p, key, m,
-                         row_ids=nid_rows, row_sub=sub_rows)
-            if noise is not None else None)
-    zp = jnp.asarray(aq.zero / aq.scale, jnp.float32)
+    with jax.named_scope("cim.noise"):
+        nctx = (_layer_noise(lp, cfg, noise, gamma_p, key, m,
+                             row_ids=nid_rows, row_sub=sub_rows)
+                if noise is not None else None)
     if sharded and lp.shard is not None:
         dp_hat = _sharded_schedule(lp, cfg, aq.q, zp, wqq, gamma_p, beta_p,
                                    matmul=matmul, nctx=nctx)
     else:
         dp_hat = _schedule_rows(lp, cfg, aq.q, zp, wqq, gamma_p, beta_p,
                                 matmul=matmul, nctx=nctx)
-    y = dp_hat[:, :n] * aq.scale * bind["w_scale"]
-    if lp.activation == "relu":
-        y = jax.nn.relu(y)
-    elif lp.activation != "none":
-        raise ValueError(f"unknown activation {lp.activation!r}")
-    return y
+    with jax.named_scope("cim.epilogue"):
+        y = dp_hat[:, :n] * aq.scale * bind["w_scale"]
+        if lp.activation == "relu":
+            y = jax.nn.relu(y)
+        elif lp.activation != "none":
+            raise ValueError(f"unknown activation {lp.activation!r}")
+        return y
 
 
 def _run_layer(lp: LayerPlan, bind: Dict[str, jnp.ndarray], x: jnp.ndarray,
@@ -823,34 +869,37 @@ def _run_layer(lp: LayerPlan, bind: Dict[str, jnp.ndarray], x: jnp.ndarray,
     out_h*out_w GEMM rows (plus an intra-sample counter for the noise
     draws), a dense layer uses them as-is."""
     g = lp.spec.conv
-    if g is not None:
-        if x.ndim != 4 or x.shape[1:] != g.spatial_in:
-            raise ValueError(
-                f"conv layer expects (B, {g.h}, {g.w}, {g.c_in}) "
-                f"activations, got {x.shape}")
-        b = x.shape[0]
-        rep = g.out_h * g.out_w
-        x2 = im2col_patches(x, g).reshape(b * rep, lp.spec.k)
-        seg_rows = None if seg is None else jnp.repeat(seg, rep)
-        nid_rows = None if nids is None else jnp.repeat(nids, rep)
-        sub_rows = (None if nids is None else
-                    jnp.tile(jnp.arange(rep, dtype=jnp.int32), b))
-    else:
-        x2 = x.reshape(x.shape[0], -1)        # conv -> dense flatten (NHWC)
-        if x2.shape[-1] != lp.spec.k:
-            raise ValueError(f"dense layer expects {lp.spec.k} features, "
-                             f"got {x2.shape[-1]} from {x.shape}")
-        seg_rows, nid_rows, sub_rows = seg, nids, None
+    with jax.named_scope("cim.im2col"):
+        if g is not None:
+            if x.ndim != 4 or x.shape[1:] != g.spatial_in:
+                raise ValueError(
+                    f"conv layer expects (B, {g.h}, {g.w}, {g.c_in}) "
+                    f"activations, got {x.shape}")
+            b = x.shape[0]
+            rep = g.out_h * g.out_w
+            x2 = im2col_patches(x, g).reshape(b * rep, lp.spec.k)
+            seg_rows = None if seg is None else jnp.repeat(seg, rep)
+            nid_rows = None if nids is None else jnp.repeat(nids, rep)
+            sub_rows = (None if nids is None else
+                        jnp.tile(jnp.arange(rep, dtype=jnp.int32), b))
+        else:
+            x2 = x.reshape(x.shape[0], -1)    # conv -> dense flatten (NHWC)
+            if x2.shape[-1] != lp.spec.k:
+                raise ValueError(f"dense layer expects {lp.spec.k} "
+                                 f"features, got {x2.shape[-1]} from "
+                                 f"{x.shape}")
+            seg_rows, nid_rows, sub_rows = seg, nids, None
     y = _layer_tiles(lp, bind, x2, cfg, matmul=matmul, key=key,
                      noise=noise, sharded=sharded, seg_rows=seg_rows,
                      nid_rows=nid_rows, sub_rows=sub_rows)
-    if g is not None:
-        y = y.reshape(b, g.out_h, g.out_w, g.c_out)
-    if lp.pool > 1:
-        y = jax.lax.reduce_window(
-            y, -jnp.inf, jax.lax.max, (1, lp.pool, lp.pool, 1),
-            (1, lp.pool, lp.pool, 1), "VALID")
-    return y
+    with jax.named_scope("cim.epilogue"):
+        if g is not None:
+            y = y.reshape(b, g.out_h, g.out_w, g.c_out)
+        if lp.pool > 1:
+            y = jax.lax.reduce_window(
+                y, -jnp.inf, jax.lax.max, (1, lp.pool, lp.pool, 1),
+                (1, lp.pool, lp.pool, 1), "VALID")
+        return y
 
 
 def _kernel_matmul(lp: LayerPlan, cfg: EngineConfig):
@@ -910,14 +959,16 @@ def _forward(plan: NetworkPlan, binds: Sequence[Dict[str, jnp.ndarray]],
                 f"input shape {x.shape} != first conv layer's "
                 f"(..., {g0.h}, {g0.w}, {g0.c_in})")
         lead = x.shape[:-3]
-        xc = x.reshape((-1,) + x.shape[-3:]).astype(jnp.float32)
+        with jax.named_scope("cim.act_quant"):
+            xc = x.reshape((-1,) + x.shape[-3:]).astype(jnp.float32)
     else:
         k0 = plan.layers[0].spec.k
         if x.shape[-1] != k0:
             raise ValueError(
                 f"input width {x.shape[-1]} != first layer's k={k0}")
         lead = x.shape[:-1]
-        xc = x.reshape((-1, x.shape[-1])).astype(jnp.float32)
+        with jax.named_scope("cim.act_quant"):
+            xc = x.reshape((-1, x.shape[-1])).astype(jnp.float32)
     noisy = noise is not None
     sharded = (not reference) and plan.cfg.sharding is not None
     if seg is not None and seg.shape[0] != xc.shape[0]:
@@ -928,13 +979,15 @@ def _forward(plan: NetworkPlan, binds: Sequence[Dict[str, jnp.ndarray]],
                          f"batch extent {xc.shape[0]}")
     for i, (lp, bind) in enumerate(zip(plan.layers, binds)):
         if m_valid is not None:       # batch-bucketed run: re-pin pad rows
-            xc = _mask_pad_rows(xc, m_valid)
+            with jax.named_scope("cim.act_quant"):
+                xc = _mask_pad_rows(xc, m_valid)
         mk = _reference_matmul if reference else _kernel_matmul
         lkey = jax.random.fold_in(key, i) if noisy else None
         xc = _run_layer(lp, bind, xc, plan.cfg, matmul=mk(lp, plan.cfg),
                         key=lkey, noise=noise, sharded=sharded, seg=seg,
                         nids=nids)
-    return xc.reshape(lead + xc.shape[1:])
+    with jax.named_scope("cim.epilogue"):
+        return xc.reshape(lead + xc.shape[1:])
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "bound", "reference"))

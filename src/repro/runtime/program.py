@@ -169,6 +169,25 @@ def _bind_jit(plan: rt.NetworkPlan, params: rt.Params):
     return list(rt.bind_network(plan, list(params)))
 
 
+def _pad_rows(bucket: int, xc: jnp.ndarray, seg, nid):
+    """Pad the canonical batch up to `bucket` rows with copies of row 0.
+
+    Pad ids mirror the pad rows: the pad rows stay duplicates inside row
+    0's segment, so no segment's min/max can move and live rows stay
+    bit-exact."""
+    with jax.named_scope("cim.act_quant"):
+        m = xc.shape[0]
+        pad = jnp.broadcast_to(xc[:1], (bucket - m,) + xc.shape[1:])
+        xc = jnp.concatenate([xc, pad], axis=0)
+        if seg is not None:
+            seg = jnp.concatenate(
+                [seg, jnp.broadcast_to(seg[:1], (bucket - m,))])
+        if nid is not None:
+            nid = jnp.concatenate(
+                [nid, jnp.broadcast_to(nid[:1], (bucket - m,))])
+        return xc, seg, nid
+
+
 class CIMProgram:
     """An immutable, hashable compiled CIM inference artifact.
 
@@ -332,37 +351,33 @@ class CIMProgram:
                       key, noise, reference: bool,
                       segments=None, noise_ids=None,
                       point: str = "") -> jnp.ndarray:
-        nz = rt._dispatch_noise(self._plan, noise)
-        xc, lead = self._canon(x)
-        m = xc.shape[0]
-        if m < 1:
-            raise ValueError("cannot serve an empty batch")
-        seg = self._canon_rows(segments, m, "segments")
-        nid = self._canon_rows(noise_ids, m, "noise_ids")
-        bucket = self._buckets.bucket_for(m)
-        if bucket > m:
-            pad = jnp.broadcast_to(xc[:1], (bucket - m,) + xc.shape[1:])
-            xc = jnp.concatenate([xc, pad], axis=0)
-            # pad ids mirror the pad rows (copies of row 0): the pad rows
-            # stay duplicates inside row 0's segment, so no segment's
-            # min/max can move and live rows stay bit-exact
-            if seg is not None:
-                seg = jnp.concatenate(
-                    [seg, jnp.broadcast_to(seg[:1], (bucket - m,))])
-            if nid is not None:
-                nid = jnp.concatenate(
-                    [nid, jnp.broadcast_to(nid[:1], (bucket - m,))])
-        self._note_executable(
-            executable_key("bucket", bucket, noise=nz is not None,
-                           keyed=key is not None, devices=self._devices(),
-                           bound=bound, reference=reference,
-                           segmented=seg is not None,
-                           identity=nid is not None,
-                           point=str(point)), bucketed=True)
-        y = rt._exec_jit(self._plan, payload, xc,
-                         jnp.asarray(m, jnp.int32), key, nz, seg, nid,
-                         bound, reference)
-        return y[:m].reshape(lead + y.shape[1:])
+        """The host side of every bucketed dispatch (bucket lookup,
+        padding, executable call), inside the `repro.serve` profiler span
+        with the live `rows` and the `bucket` as its arguments."""
+        with jax.profiler.TraceAnnotation("repro.serve") as span:
+            nz = rt._dispatch_noise(self._plan, noise)
+            xc, lead = self._canon(x)
+            m = xc.shape[0]
+            if m < 1:
+                raise ValueError("cannot serve an empty batch")
+            seg = self._canon_rows(segments, m, "segments")
+            nid = self._canon_rows(noise_ids, m, "noise_ids")
+            bucket = self._buckets.bucket_for(m)
+            span.set_metadata(rows=m, bucket=bucket)
+            if bucket > m:
+                xc, seg, nid = _pad_rows(bucket, xc, seg, nid)
+            self._note_executable(
+                executable_key("bucket", bucket, noise=nz is not None,
+                               keyed=key is not None, devices=self._devices(),
+                               bound=bound, reference=reference,
+                               segmented=seg is not None,
+                               identity=nid is not None,
+                               point=str(point)), bucketed=True)
+            y = rt._exec_jit(self._plan, payload, xc,
+                             jnp.asarray(m, jnp.int32), key, nz, seg, nid,
+                             bound, reference)
+            with jax.named_scope("cim.epilogue"):
+                return y[:m].reshape(lead + y.shape[1:])
 
     # -- observability -----------------------------------------------------
 

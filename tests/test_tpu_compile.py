@@ -89,3 +89,32 @@ def test_ring_decode_attention_compiles_at_olmo_heads(one_chip):
     _compiled_text(ring_decode_attention, shape((r, h, hd)),
                    shape((r, n_l, h, hd)), shape((r, n_l, h, hd)),
                    shape((r, n_l)))
+
+
+def test_cim_kernel_call_carries_its_scope(one_chip):
+    """The Mosaic call of a bound engine executable is charged to the
+    `cim.kernel` scope in a TPU trace: its op_name holds the scope."""
+    import re
+
+    from repro.core import mapping
+    from repro.runtime import engine as rt
+
+    plan = rt.plan_network([mapping.LayerSpec(m=256, k=1152, n=256)])
+    binds = jax.eval_shape(lambda: rt.bind_network(
+        plan, rt.init_network_params(plan, jax.random.PRNGKey(0))))
+
+    def shape(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    text = _compiled_text(
+        lambda b, x, m: rt._exec_jit(plan, b, x, m, None, None, None, None,
+                                     bound=True, reference=False),
+        jax.tree.map(shape, binds),
+        shape(jax.ShapeDtypeStruct((256, 1152), jnp.float32)),
+        shape(jax.ShapeDtypeStruct((), jnp.int32)))
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    for line in calls:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "/cim.kernel/" in op_name, op_name
