@@ -12,10 +12,11 @@ r_out); `kernel_variant` returns a jit-compiled kernel specialized to that
 point (plane walk + accumulator shift from r_in, ADC epilogue from r_out)
 and caches it, so a network executes through a small table of compiled
 variants instead of re-tracing per layer.  `kernel_variant_for_tile`
-additionally keys the cache on the dispatched tile geometry — block sizes
-clamped to one (rows, k, n) macro tile — so the smaller per-device tiles
-of a sharded schedule do not pad up to full-macro blocks.  The runtime
-engine (repro/runtime/engine.py) is the intended caller.
+additionally keys the cache on the dispatched call's geometry — block
+sizes clamped to its (rows, k, n): one row tile's K by the local column
+extent — so the smaller per-device extents of a sharded schedule do not
+pad up to full-width blocks.  The runtime engine
+(repro/runtime/engine.py) is the intended caller.
 
 Units: inputs/weights are integer codes (unsigned < 2^r_in / odd ints in
 +/-(2^r_w - 1)); outputs are int32 ADC codes in [0, 2^r_out) — or raw
@@ -189,14 +190,15 @@ def block_candidates(rows: int, k: int, n: int, n_planes: int = 1,
 def kernel_variant_for_tile(prec: KernelPrecision, rows: int, k: int, n: int,
                             *, bm: int = 256, bn: int = 256, bk: int = 512,
                             fuse_adc: bool = True) -> Callable:
-    """Kernel variant fitted to one dispatched tile's geometry.
+    """Kernel variant fitted to one dispatched call's geometry.
 
     Args:
       prec: the (r_in, r_w, r_out) operating point.
-      rows, k, n: the tile's GEMM shape — stream-chunk rows x row-tile K x
-        col-tile N.  Under a sharded schedule these are the *per-device*
-        extents, so each device compiles blocks sized to its own tile
-        instead of padding to the full-macro defaults.
+      rows, k, n: the call's GEMM shape — stream-chunk rows x row-tile K x
+        the local column extent (every col tile the caller holds).  Under a
+        sharded schedule these are the *per-device* extents, so each
+        device compiles blocks sized to its own share instead of padding
+        to full-width blocks.
       bm, bn, bk: preferred block sizes, fitted by `fit_blocks`.
     Returns:
       The cached callable of `kernel_variant` at the fitted block sizes —

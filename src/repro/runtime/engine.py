@@ -5,7 +5,10 @@ The paper's headline lever is workload-adaptive 8-to-1b precision scaling
 `mapping.LayerSpec`s is *planned* into the macro's row/col tile schedule
 (core/mapping.py) and *executed* through precision-specialized, jit-compiled
 Pallas kernel variants (kernels/cim_mbiw/ops.kernel_variant), with the
-chip's digital partial-sum recombination between row tiles.
+chip's digital partial-sum recombination between row tiles.  Col tiles
+stay the macro's unit of work (macro_evals, noise draws, col sharding),
+but they never interact numerically, so execution dispatches one kernel
+call per row tile over all of a device's col tiles.
 
     specs = [LayerSpec(m=256, k=1152, n=64, r_in=4, r_w=2), ...]
     engine = CIMInferenceEngine(specs)           # plans + builds dispatch
@@ -65,9 +68,9 @@ signed-to-unsigned conversion + beta block does.
 Per-layer precision is free: each layer's (r_in, r_w, r_out) selects its
 kernel variant from a small cached table, so a mixed-precision network
 compiles one kernel per distinct operating point, not per layer; the
-variant's block sizes are clamped to the dispatched tile geometry
+variant's block sizes are clamped to the dispatched call's geometry
 (ops.kernel_variant_for_tile), so a sharded schedule's smaller per-device
-tiles do not pad up to full-macro blocks.
+extents do not pad up to full-width blocks.
 
 Noise-injected mode (post-silicon studies, paper Sec. III.E/V.A): with
 `EngineConfig(noise=NoiseConfig(...))` the full equivalent noise model runs
@@ -102,11 +105,11 @@ carries the scope; an op belongs to the innermost taxonomy scope there):
   cim.act_quant   pad-row pinning, activation quantization, zero-point,
                   bucket padding, a projection's entry reshape
   cim.im2col      im2col patches, reshape to GEMM rows, id repeats
-  cim.zp_fold     per-tile weight column sums, beta_eff
+  cim.zp_fold     per-row-tile weight column sums, beta_eff
   cim.planes      plane split, K/row/col padding, kernel operand slices,
                   the ADC gain pin before the pallas_call
   cim.kernel      the pallas_call (HLO name `cim_mbiw`)
-  cim.recombine   dequant/accumulate of codes, tile and chunk concat
+  cim.recombine   dequant/accumulate of codes, stream-chunk concat
   cim.epilogue    scale multiply, activation, max-pool, reshape back
   cim.noise       noise fields and the noisy ADC epilogue
   lm.embed, lm.norm, lm.attention (RoPE, scores, softmax, PV),
@@ -212,8 +215,10 @@ class EngineConfig:
     gamma_bits: int = -1             # -1: continuous gamma; >=0: HW quant
     max_gamma: float = 32.0
     bm: int = 128                    # kernel block sizes (MXU-aligned),
-    bn: int = 128                    # clamped per dispatched tile geometry
-    bk: int = 256
+    bn: int = 512                    # clamped per dispatched call geometry:
+    bk: int = 1152                   # a row tile's whole K (<= one macro's
+                                     # rows) is one K block per plane, so the
+                                     # weight block stays put across planes
     stream_rows: int = 0             # im2col streaming: GEMM rows per kernel
                                      # dispatch (0 = single dispatch); bounds
                                      # the Pallas working set for large maps
@@ -534,7 +539,7 @@ class _LayerNoise:
     """Per-layer noise context of one engine run (built at trace time).
 
     `offset_codes`/`droop_codes` are per padded output column (code units);
-    tiles slice them.  `gain_mult` collects the deterministic INL terms
+    a col shard slices them.  `gain_mult` collects the deterministic INL terms
     (DPL settling, MBIW charge injection) as a multiplier on the code gain.
     `thermal` holds the pre-drawn kT/C noise in dp units for every
     (row tile, col tile) over the layer's full GEMM-row extent — shape
@@ -633,21 +638,20 @@ def _layer_noise(lp: LayerPlan, cfg: EngineConfig, noise: NoiseConfig,
         thermal=thermal)
 
 
-def _noise_adc_code(lp: LayerPlan, dp: jnp.ndarray, gamma_t: jnp.ndarray,
+def _noise_adc_code(lp: LayerPlan, dp: jnp.ndarray, gamma: jnp.ndarray,
                     beta_eff: jnp.ndarray, nctx: _LayerNoise,
-                    n_slice: Tuple[int, int],
                     thermal: jnp.ndarray) -> jnp.ndarray:
-    """ADC conversion of one macro tile's raw dp with the noise terms
-    applied pre-floor — the engine-side mirror of fakequant's
-    adc_quantize(dp + thermal, gain, beta + offsets).  `thermal` is the
-    tile's pre-drawn kT/C slice (dp units, already row-aligned)."""
-    ns, ne = n_slice
+    """ADC conversion of one row tile's raw dp over the local columns with
+    the noise terms applied pre-floor — the engine-side mirror of
+    fakequant's adc_quantize(dp + thermal, gain, beta + offsets).
+    `thermal` is the row tile's pre-drawn kT/C field laid out over the
+    local columns (dp units, already row-aligned)."""
     dp = dp.astype(jnp.float32) + thermal
     mid = 2.0 ** (lp.spec.r_out - 1)
-    code = jnp.floor(mid + rounding_barrier(gamma_t * lp.g0
+    code = jnp.floor(mid + rounding_barrier(gamma * lp.g0
                                             * nctx.gain_mult * dp)
                      + beta_eff
-                     + nctx.offset_codes[ns:ne] - nctx.droop_codes[ns:ne])
+                     + nctx.offset_codes - nctx.droop_codes)
     return jnp.clip(code, 0.0, 2.0 ** lp.spec.r_out - 1.0).astype(jnp.int32)
 
 
@@ -655,57 +659,53 @@ def _tile_schedule(lp: LayerPlan, q_rows: jnp.ndarray, zp: jnp.ndarray,
                    wqq: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
                    *, matmul,
                    nctx: Optional[_LayerNoise] = None) -> jnp.ndarray:
-    """One block of GEMM rows through a (k, n) tile schedule.
+    """One block of GEMM rows through the layer's tile schedule.
 
     `wqq`/`gamma`/`beta` span a whole number of uniform col tiles (the
     caller's local column extent — all tiles on a single-device run, one
-    device's tiles under col sharding); `matmul` evaluates one macro tile
-    (kernel variant or jnp oracle) and returns int32 ADC codes — or raw
-    int32 dp when a noise context is given, in which case the ADC
-    conversion (with the noise terms and the tile's pre-drawn thermal
-    slice) runs here.  Returns dp_hat (rows, local cols) in dp units."""
+    device's tiles under col sharding).  Col tiles never interact: the ADC
+    floor, ABN gain/offset, zero-point fold and dequant are per output
+    column and g0 is per layer, so each row tile is one `matmul` dispatch
+    over the whole local extent.  Only row tiles keep their own ADC
+    conversion and are recombined digitally.  `matmul` returns int32 ADC
+    codes — or raw int32 dp when a noise context is given, in which case
+    the ADC conversion (with the noise terms and the row tile's pre-drawn
+    thermal field) runs here.  Returns dp_hat (rows, local cols) in dp
+    units."""
     mid = 2.0 ** (lp.spec.r_out - 1)
     g0 = lp.g0
-    tsz = lp.tile_n
     with jax.named_scope("cim.recombine"):
         # materialized ADC gain: the fakequant reference and this schedule
         # must dequantize with the identical float in every fusion context
         # (quantization.rounding_barrier)
         gain = rounding_barrier(gamma * g0)
-    dp_hat = []
-    for ni in range(wqq.shape[1] // tsz):
-        ns, ne = ni * tsz, (ni + 1) * tsz
+        acc = jnp.zeros((q_rows.shape[0], wqq.shape[1]), jnp.float32)
+    for ki, (ks, ksz) in enumerate(lp.k_slices):
+        ke = ks + ksz
+        with jax.named_scope("cim.zp_fold"):
+            # zero-point: x = q*s + z -> z*colsum is per-channel constant,
+            # folded into the ABN offset inside the ADC floor
+            zp_dp = zp * jnp.sum(wqq[ks:ke], axis=0)
+            beta_eff = beta + rounding_barrier(gain * zp_dp)
+        with jax.named_scope("cim.planes"):
+            # the kernel's own scopes (cim.kernel) are innermost
+            out = matmul(q_rows[:, ks:ke], wqq[ks:ke], gamma, beta_eff, g0)
+        if nctx is None:
+            codes = out
+        else:
+            with jax.named_scope("cim.noise"):
+                # (local col tiles, rows, tile_n) -> (rows, local cols):
+                # every element keeps the draw of its (row tile, col tile)
+                th = jnp.swapaxes(nctx.thermal[ki], 0, 1).reshape(
+                    q_rows.shape[0], wqq.shape[1])
+                codes = _noise_adc_code(lp, out, gamma, beta_eff, nctx, th)
         with jax.named_scope("cim.recombine"):
-            acc = jnp.zeros((q_rows.shape[0], tsz), jnp.float32)
-        for ki, (ks, ksz) in enumerate(lp.k_slices):
-            ke = ks + ksz
-            with jax.named_scope("cim.zp_fold"):
-                # zero-point: x = q*s + z -> z*colsum is per-channel
-                # constant, folded into the ABN offset inside the ADC floor
-                zp_dp = zp * jnp.sum(wqq[ks:ke, ns:ne], axis=0)
-                beta_eff = beta[ns:ne] + rounding_barrier(gain[ns:ne]
-                                                          * zp_dp)
-            with jax.named_scope("cim.planes"):
-                # the kernel's own scopes (cim.kernel) are innermost
-                out = matmul(q_rows[:, ks:ke], wqq[ks:ke, ns:ne],
-                             gamma[ns:ne], beta_eff, g0)
-            if nctx is None:
-                codes = out
-            else:
-                with jax.named_scope("cim.noise"):
-                    codes = _noise_adc_code(lp, out, gamma[ns:ne], beta_eff,
-                                            nctx, (ns, ne),
-                                            nctx.thermal[ki, ni])
-            with jax.named_scope("cim.recombine"):
-                # digital partial-sum recombination in dp units;
-                # dequantizing against the *raw* beta keeps the zero-point
-                # contribution in dp_hat, exactly like the fakequant
-                # training path
-                acc = acc + (codes.astype(jnp.float32) + 0.5 - mid
-                             - beta[None, ns:ne]) / gain[None, ns:ne]
-        dp_hat.append(acc)
-    with jax.named_scope("cim.recombine"):
-        return jnp.concatenate(dp_hat, axis=-1)
+            # digital partial-sum recombination in dp units; dequantizing
+            # against the *raw* beta keeps the zero-point contribution in
+            # dp_hat, exactly like the fakequant training path
+            acc = acc + (codes.astype(jnp.float32) + 0.5 - mid
+                         - beta[None, :]) / gain[None, :]
+    return acc
 
 
 def _schedule_rows(lp: LayerPlan, cfg: EngineConfig, q_rows: jnp.ndarray,
@@ -912,9 +912,9 @@ def _kernel_matmul(lp: LayerPlan, cfg: EngineConfig):
         else (cfg.bm, cfg.bn, cfg.bk)
 
     def matmul(xq, wqt, gamma_t, beta_t, g0):
-        # variant cache keyed on the dispatched tile geometry: per-device
-        # tiles of a sharded schedule get fitted block sizes, not
-        # full-macro padding
+        # variant cache keyed on the dispatched call's geometry (a row
+        # tile's K x the local column extent): the per-device extents of
+        # a sharded schedule get fitted block sizes, not full-width padding
         fn = kops.kernel_variant_for_tile(
             lp.precision, xq.shape[0], xq.shape[1], wqt.shape[1],
             bm=bm, bn=bn, bk=bk, fuse_adc=fuse)

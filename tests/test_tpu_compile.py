@@ -80,6 +80,34 @@ def _compile_cim(dev, r_in, rows, k, n, fuse_adc):
                    shape((n,), jnp.float32), shape((n,), jnp.float32))
 
 
+# one row tile dispatched over a whole projection's columns at olmo-1b's
+# widths, with the blocks the engine's defaults fit to it: a decode step's
+# widest (64 rows, per-row beta) and a prefill of 64 x 128 prompt tokens
+ROW_TILE_CALLS = [(64, 1024, 8192, True, (64, 512, 1024)),
+                  (8192, 1024, 2048, False, (128, 512, 1024))]
+
+
+@pytest.mark.parametrize("rows,k,n,per_row_beta,blocks", ROW_TILE_CALLS,
+                         ids=["decode", "prefill"])
+def test_cim_mbiw_row_tile_dispatch_compiles(one_chip, rows, k, n,
+                                             per_row_beta, blocks):
+    from repro.runtime.engine import EngineConfig
+
+    cfg, prec = EngineConfig(), ops.KernelPrecision(8, 4, 8)
+    assert ops.fit_blocks(prec.n_planes, rows, k, n, cfg.bm, cfg.bn,
+                          cfg.bk) == blocks
+    fn = ops.kernel_variant_for_tile(prec, rows, k, n, bm=cfg.bm, bn=cfg.bn,
+                                     bk=cfg.bk)
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    beta = (rows, n) if per_row_beta else (n,)
+    _compiled_text(lambda x, w, g, b: fn(x, w, g, b, 0.01),
+                   shape((rows, k), jnp.int32), shape((k, n), jnp.float32),
+                   shape((n,), jnp.float32), shape(beta, jnp.float32))
+
+
 def test_ring_decode_attention_compiles_at_olmo_heads(one_chip):
     r, n_l, h, hd = 4, 16, 16, 128
 

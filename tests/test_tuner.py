@@ -176,11 +176,12 @@ def test_compile_program_tune_bitexact():
     schedule_report echoes the chosen blocks and predicted cost."""
     clear_program_cache()
     specs = (LayerSpec(m=16, k=300, n=40, r_in=4, r_w=2),)
-    p0 = compile_program(specs, EngineConfig())
-    pa = compile_program(specs, EngineConfig(), tune="analytic",
-                         tune_cache="")
-    pm = compile_program(specs, EngineConfig(), tune="measure",
-                         tune_cache="")
+    # a config whose preferred bk (256) is no whole row tile, so the
+    # heuristic pads K and the search has a win to find
+    cfg = EngineConfig(bk=256)
+    p0 = compile_program(specs, cfg)
+    pa = compile_program(specs, cfg, tune="analytic", tune_cache="")
+    pm = compile_program(specs, cfg, tune="measure", tune_cache="")
     params = p0.init_params(jax.random.PRNGKey(0))
     x = jax.nn.relu(jax.random.normal(jax.random.PRNGKey(1), (5, 300)))
     y0 = np.asarray(p0.bind(params).serve(x))
