@@ -17,6 +17,7 @@ ARCH_IDS = [
     "internvl2_76b",
     "mamba2_1_3b",
     "whisper_medium",
+    "deepseek_v2_lite",
 ]
 
 _ALIASES = {
@@ -30,6 +31,7 @@ _ALIASES = {
     "internvl2-76b": "internvl2_76b",
     "mamba2-1.3b": "mamba2_1_3b",
     "whisper-medium": "whisper_medium",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

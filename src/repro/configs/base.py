@@ -30,6 +30,25 @@ class ModelConfig:
     moe_experts: int = 0
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
+    moe_d_ff: int = 0             # expert width; 0 -> d_ff
+    moe_shared_d_ff: int = 0      # shared experts' SwiGLU width; 0 = none
+    moe_norm_topk: bool = True    # renormalise the top-k route weights
+    moe_routed_scale: float = 1.0  # route weights x this (routed_scaling)
+    moe_held: int = 0             # >0: a dropless layer that holds experts
+    moe_share: int = 0            # [share*held, (share+1)*held) of them
+    dense_layers: int = 0         # leading dense-FFN layers of a moe stack
+    # latent attention (MLA, DeepSeek-V2); kv_lora_rank 0 = per-head K/V
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (arXiv:2309.00071); factor 0 = plain rope
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original: int = 4096
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
     # hybrid (recurrentgemma / griffin)
     attn_every: int = 0           # every k-th layer is local attention
     local_window: int = 2048

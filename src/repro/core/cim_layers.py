@@ -310,6 +310,81 @@ def _engine_forward(params: Dict, x: jnp.ndarray, cfg: CIMConfig,
         return y.reshape(lead + (n,)).astype(x.dtype)
 
 
+def group_block_rows(cfg: CIMConfig) -> int:
+    """Rows of one block of a grouped call's row layout (see
+    cim_grouped_apply): a group's rows start at a multiple of this."""
+    return _engine_config(cfg).bm
+
+
+def init_cim_grouped(key: jax.Array, groups: int, k: int, n: int,
+                     cfg: Optional[CIMConfig] = None) -> Dict:
+    """G CIM linears of one (K, N) shape stacked on a leading group axis,
+    each initialised as init_cim_linear initialises one."""
+    return jax.vmap(lambda kk: init_cim_linear(kk, k, n, cfg=cfg))(
+        jax.random.split(key, groups))
+
+
+def cim_grouped_apply(params: Dict, x: jnp.ndarray, row_group: jnp.ndarray,
+                      cfg: CIMConfig, *, ranges=None,
+                      key: Optional[jax.Array] = None, group_rows: int = 0,
+                      reference: bool = False) -> jnp.ndarray:
+    """y[r] ~= x[r] @ w[row_group[r]]: one CIM layer shape over G weight
+    sets, one kernel call a row tile whatever G is.
+
+    params: {"w" (G, K, N), "abn_log_gamma" (G, N), "abn_beta" (G, N)};
+    x: (M, K) rows laid out in blocks of `group_block_rows(cfg)` rows of
+    one group each, a group's rows from a block boundary; row_group: (M,)
+    int32 group of each row, G for a padding row (its output is
+    meaningless).  In engine mode a group's activation range is taken over
+    its own live rows (runtime.engine.grouped_layer), or is `ranges`
+    (engine.group_ranges) when the layout is served in chunks;
+    `key`/`group_rows` feed its noise model, `reference` its pure-jnp
+    oracle.  Bypass mode is the float matmul.  Other modes raise
+    ValueError."""
+    if cfg.mode == "bypass":
+        from repro.runtime.engine import _block_vectors
+        bm = group_block_rows(cfg)
+        grp = _block_vectors(row_group, params["w"].shape[0], bm)[0]
+        y = jnp.einsum("bmk,bkn->bmn", x.reshape(grp.shape[0], bm, -1),
+                       params["w"][grp].astype(x.dtype))
+        return y.reshape(x.shape[0], -1)
+    if cfg.mode != "engine":
+        raise ValueError(f"a grouped CIM call does not support mode "
+                         f"{cfg.mode!r}; use engine or bypass")
+    from repro.runtime import engine as rt
+    g, k_dim, n = params["w"].shape
+    spec = mapping.LayerSpec(m=x.shape[0], k=k_dim, n=n, r_in=cfg.r_in,
+                             r_w=cfg.r_w, r_out=cfg.r_out)
+    with jax.named_scope("cim.act_quant"):
+        x2 = rounding_barrier(x)
+    y = rt.grouped_layer(spec, _engine_config(cfg), params, x2, row_group,
+                         ranges=ranges, reference=reference, key=key,
+                         group_rows=group_rows)
+    with jax.named_scope("cim.epilogue"):
+        return rounding_barrier(y).astype(x.dtype)
+
+
+def cim_grouped_dense_apply(params: Dict, x: jnp.ndarray, cfg: CIMConfig,
+                            *, key: Optional[jax.Array] = None,
+                            reference: bool = False) -> jnp.ndarray:
+    """(G, R, K) -> (G, R, N): every group's R rows through its own
+    weights in one grouped call (cim_grouped_apply), each group's
+    activation range over its R rows."""
+    g, r, k_dim = x.shape
+    if cfg.mode == "bypass":
+        return jnp.einsum("grk,gkn->grn", x, params["w"].astype(x.dtype))
+    bm = group_block_rows(cfg)
+    r_pad = -(-r // bm) * bm
+    xp = jnp.pad(x, ((0, 0), (0, r_pad - r), (0, 0)))
+    live = jnp.arange(r_pad, dtype=jnp.int32) < r
+    row_group = jnp.where(live[None, :],
+                          jnp.arange(g, dtype=jnp.int32)[:, None], g)
+    y = cim_grouped_apply(params, xp.reshape(g * r_pad, k_dim),
+                          row_group.reshape(-1), cfg, key=key,
+                          group_rows=r, reference=reference)
+    return y.reshape(g, r_pad, -1)[:, :r]
+
+
 def _sim_forward(params: Dict, x: jnp.ndarray, cfg: CIMConfig,
                  key: Optional[jax.Array]) -> jnp.ndarray:
     """Voltage-domain path: tile per mapping.py and run the behavioural

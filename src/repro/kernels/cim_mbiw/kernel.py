@@ -51,6 +51,40 @@ def plane_layout(r_in: int) -> tuple[int, int]:
     return shift, -(-r_in // shift)
 
 
+def _accumulate(x_ref, w_ref, acc_ref, k, n_k_inner: int,
+                plane_shift: int):
+    """acc += 2^(plane_shift*plane) * (x block . w block) for grid step k
+    of the plane-major K walk."""
+    plane = k // n_k_inner
+    scale = (jnp.int32(1) << (plane_shift * plane)).astype(jnp.int32)
+    part = jax.lax.dot_general(
+        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
+    acc_ref[...] += scale * part
+
+
+def _write_codes(acc_ref, gain_ref, beta_ref, o_ref, *, r_out: int,
+                 fuse_adc: bool, interpret: bool):
+    """The ADC epilogue: the accumulated dp to codes, in VMEM."""
+    if not fuse_adc:
+        # raw-dp mode: the caller owns the ADC conversion (the engine's
+        # noise epilogue injects pre-floor terms it cannot fuse here)
+        o_ref[...] = acc_ref[...]
+        return
+    dp = acc_ref[...].astype(jnp.float32)
+    mid = 2.0 ** (r_out - 1)
+    # float-op lockstep with ref.py: one rounded product, then
+    # (mid + t) + beta.  The interpreter's XLA backend may FMA-contract
+    # the product into the add in some fusion contexts (e.g. inside a
+    # scan body) but not others, so there the product is pinned;
+    # Mosaic keeps the two ops apart and has no lowering for the pin.
+    t = gain_ref[...] * dp                             # (1, bn) * (bm, bn)
+    if interpret:
+        t = jax.lax.optimization_barrier(t)
+    code = jnp.floor(mid + t + beta_ref[...])          # beta (1|bm, bn)
+    o_ref[...] = jnp.clip(code, 0.0, 2.0 ** r_out - 1.0).astype(jnp.int32)
+
+
 def _cim_mbiw_kernel(x_ref, w_ref, gain_ref, beta_ref, o_ref, acc_ref, *,
                      n_k_total: int, n_k_inner: int, plane_shift: int,
                      r_out: int, fuse_adc: bool, interpret: bool):
@@ -60,33 +94,12 @@ def _cim_mbiw_kernel(x_ref, w_ref, gain_ref, beta_ref, o_ref, acc_ref, *,
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    plane = k // n_k_inner
-    scale = (jnp.int32(1) << (plane_shift * plane)).astype(jnp.int32)
-    part = jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
-    acc_ref[...] += scale * part
+    _accumulate(x_ref, w_ref, acc_ref, k, n_k_inner, plane_shift)
 
     @pl.when(k == n_k_total - 1)
     def _epilogue():
-        if not fuse_adc:
-            # raw-dp mode: the caller owns the ADC conversion (the engine's
-            # noise epilogue injects pre-floor terms it cannot fuse here)
-            o_ref[...] = acc_ref[...]
-            return
-        dp = acc_ref[...].astype(jnp.float32)
-        mid = 2.0 ** (r_out - 1)
-        # float-op lockstep with ref.py: one rounded product, then
-        # (mid + t) + beta.  The interpreter's XLA backend may FMA-contract
-        # the product into the add in some fusion contexts (e.g. inside a
-        # scan body) but not others, so there the product is pinned;
-        # Mosaic keeps the two ops apart and has no lowering for the pin.
-        t = gain_ref[...] * dp                         # (1, bn) * (bm, bn)
-        if interpret:
-            t = jax.lax.optimization_barrier(t)
-        code = jnp.floor(mid + t + beta_ref[...])      # beta (1|bm, bn)
-        o_ref[...] = jnp.clip(code, 0.0, 2.0 ** r_out - 1.0
-                              ).astype(jnp.int32)
+        _write_codes(acc_ref, gain_ref, beta_ref, o_ref, r_out=r_out,
+                     fuse_adc=fuse_adc, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -155,3 +168,110 @@ def cim_mbiw_matmul_planes(x_planes: jnp.ndarray, w_q: jnp.ndarray,
 
     with jax.named_scope("cim.kernel"):
         return platform_call(build, x_planes, w_q, gain, beta)
+
+
+def _cim_mbiw_grouped_kernel(grp_ref, src_ref, live_ref, x_ref, w_ref,
+                             gain_ref, beta_ref, o_ref, acc_ref, *,
+                             n_k_total: int, n_k_inner: int,
+                             plane_shift: int, r_out: int, fuse_adc: bool,
+                             interpret: bool):
+    del grp_ref, src_ref          # consumed by the index maps
+    i, k = pl.program_id(0), pl.program_id(2)
+    live = live_ref[i] != 0
+
+    @pl.when(k == 0)
+    def _zero():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(live)
+    def _live():
+        _accumulate(x_ref, w_ref, acc_ref, k, n_k_inner, plane_shift)
+
+    last = k == n_k_total - 1
+
+    @pl.when(last & live)
+    def _epilogue():
+        _write_codes(acc_ref, gain_ref, beta_ref, o_ref, r_out=r_out,
+                     fuse_adc=fuse_adc, interpret=interpret)
+
+    @pl.when(last & jnp.logical_not(live))
+    def _empty():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "plane_shift", "g0", "r_out", "bm", "bn", "bk", "fuse_adc"))
+def cim_mbiw_grouped_planes(x_planes: jnp.ndarray, w_q: jnp.ndarray,
+                            gamma: jnp.ndarray, beta: jnp.ndarray,
+                            block_group: jnp.ndarray,
+                            block_src: jnp.ndarray,
+                            block_live: jnp.ndarray, *, plane_shift: int,
+                            g0: float, r_out: int, bm: int, bn: int,
+                            bk: int, fuse_adc: bool = True) -> jnp.ndarray:
+    """The CIM matmul of `cim_mbiw_matmul_planes` over a leading group
+    axis of weights: every block of `bm` GEMM rows belongs to one group
+    and meets that group's weights, ABN gain and offset.
+
+    x_planes    : (M, P*K) int8, M = B*bm, rows laid out group by group
+    w_q         : (G, K, N) int8 odd weights, one (K, N) matrix a group
+    gamma, beta : (B, 1, N) float32, the ABN gain and offset of each row
+                  block (its group's, with its zero-point folded in)
+    block_group : (B,) int32, the group whose weights a block meets
+    block_src   : (B,) int32, the row block a block reads its inputs from
+    block_live  : (B,) int32, 0 for a block that holds no live row
+
+    The three block vectors reach the index maps by scalar prefetch, so
+    the weight block follows the group.  An empty block computes nothing
+    and writes zeros; give it its predecessor's group and source block and
+    it moves no operand either.  Returns (M, N) int32 codes, or raw dp
+    with `fuse_adc=False`.  The pallas_call is named `cim_mbiw` like the
+    ungrouped one.
+    """
+    m, pk = x_planes.shape
+    n_groups, k_dim, n = w_q.shape
+    n_blocks = block_group.shape[0]
+    assert pk % k_dim == 0, (pk, k_dim)
+    n_planes = pk // k_dim
+    assert m == n_blocks * bm and n % bn == 0 and k_dim % bk == 0, (
+        m, n_blocks, bm, n, bn, k_dim, bk)
+    assert gamma.shape == beta.shape == (n_blocks, 1, n), (gamma.shape,
+                                                          beta.shape)
+    n_k_inner = k_dim // bk
+    n_k_total = n_planes * n_k_inner
+    with jax.named_scope("cim.planes"):
+        gain = jax.lax.optimization_barrier(gamma * g0)
+
+    def build(interpret: bool):
+        kernel = functools.partial(
+            _cim_mbiw_grouped_kernel, n_k_total=n_k_total,
+            n_k_inner=n_k_inner, plane_shift=plane_shift, r_out=r_out,
+            fuse_adc=fuse_adc, interpret=interpret)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n_blocks, n // bn, n_k_total),
+            in_specs=[
+                pl.BlockSpec((bm, bk),
+                             lambda i, j, k, grp, src, live: (src[i], k)),
+                pl.BlockSpec((pl.Squeezed(), bk, bn),
+                             lambda i, j, k, grp, src, live:
+                             (grp[i], k % n_k_inner, j)),
+                pl.BlockSpec((pl.Squeezed(), 1, bn),
+                             lambda i, j, k, *_: (i, 0, j)),
+                pl.BlockSpec((pl.Squeezed(), 1, bn),
+                             lambda i, j, k, *_: (i, 0, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)])
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="cim_mbiw",
+        )
+
+    with jax.named_scope("cim.kernel"):
+        return platform_call(build, block_group, block_src, block_live,
+                             x_planes, w_q, gain, beta)

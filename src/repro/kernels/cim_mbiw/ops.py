@@ -35,7 +35,9 @@ import jax.numpy as jnp
 
 from repro.core import digital_ref
 from repro.core.hw import CIMMacroConfig, DEFAULT_MACRO
-from repro.kernels.cim_mbiw.kernel import cim_mbiw_matmul_planes, plane_layout
+from repro.kernels.cim_mbiw.kernel import (cim_mbiw_grouped_planes,
+                                          cim_mbiw_matmul_planes,
+                                          plane_layout)
 
 _PLANE_SHIFT = 4  # legacy nibble-plane default (r_in > 7 inputs)
 
@@ -76,6 +78,17 @@ def _pad_to(x: jnp.ndarray, mult: Tuple[int, ...]) -> jnp.ndarray:
     if all(p == (0, 0) for p in pads):
         return x
     return jnp.pad(x, pads)
+
+
+def _pad_plane_k(x_planes: jnp.ndarray, n_planes: int, k_dim: int,
+                 k_pad: int) -> jnp.ndarray:
+    """Zero-pad every plane of a plane-major (M, P*K) operand to K + k_pad:
+    zero inputs add nothing to the dp (the macro's trick for a layer that
+    does not fill its 36-row units)."""
+    m = x_planes.shape[0]
+    xp = x_planes.reshape(m, n_planes, k_dim)
+    xp = jnp.pad(xp, ((0, 0), (0, 0), (0, k_pad)))
+    return xp.reshape(m, n_planes * (k_dim + k_pad))
 
 
 def split_planes(x_q: jnp.ndarray, r_in: int,
@@ -242,14 +255,10 @@ def cim_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, gamma: jnp.ndarray,
     shift = _PLANE_SHIFT if plane_shift is None else plane_shift
     with jax.named_scope("cim.planes"):
         x_planes, n_planes = split_planes(x_q, r_in, plane_shift)
-        # pad: K to bk multiple (per-plane), M to bm, N to bn.  Padding K
-        # with zero inputs/weights adds 0 to the dp — same trick the macro
-        # uses when a layer does not fill its 36-row units.
+        # pad: K to bk multiple (per-plane), M to bm, N to bn
         k_pad = (-k_dim) % bk
         if k_pad:
-            xp = x_planes.reshape(m, n_planes, k_dim)
-            xp = jnp.pad(xp, ((0, 0), (0, 0), (0, k_pad)))
-            x_planes = xp.reshape(m, n_planes * (k_dim + k_pad))
+            x_planes = _pad_plane_k(x_planes, n_planes, k_dim, k_pad)
             w_q = jnp.pad(w_q, ((0, k_pad), (0, 0)))
         x_planes = _pad_to(x_planes, (bm, 1))
         w_q = _pad_to(w_q.astype(jnp.int8), (1, bn))
@@ -267,6 +276,42 @@ def cim_matmul(x_q: jnp.ndarray, w_q: jnp.ndarray, gamma: jnp.ndarray,
         r_out=r_out, bm=bm, bn=bn, bk=bk, fuse_adc=fuse_adc)
     with jax.named_scope("cim.recombine"):
         return codes[:m, :n]
+
+
+def cim_matmul_grouped(x_q: jnp.ndarray, w_q: jnp.ndarray,
+                       gamma: jnp.ndarray, beta: jnp.ndarray,
+                       block_group: jnp.ndarray, block_src: jnp.ndarray,
+                       block_live: jnp.ndarray, *, r_in: int, r_out: int,
+                       g0: float, bm: int, bn: int = 512, bk: int = 1152,
+                       fuse_adc: bool = True) -> jnp.ndarray:
+    """One macro row-tile of a grouped call: int inputs -> ADC codes.
+
+    x_q: (M, K) unsigned ints < 2^r_in, M = B*bm rows laid out in row
+    blocks of one group each; w_q: (G, K, N) odd ints; gamma, beta: (B, N)
+    the ABN gain and offset of each row block; block_group/src/live: (B,)
+    int32 (see kernel.cim_mbiw_grouped_planes).  `bn`/`bk` are preferred
+    blocks, fitted to (K, N) as `fit_blocks` fits them; `bm` is the row
+    block of the layout and is used as given.  Returns (M, N) int32 codes
+    (raw int32 dp when `fuse_adc=False`)."""
+    m, k_dim = x_q.shape
+    n = w_q.shape[2]
+    shift, n_planes = plane_layout(r_in)
+    _, bn, bk = fit_blocks(n_planes, bm, k_dim, n, bm, bn, bk)
+    with jax.named_scope("cim.planes"):
+        x_planes, n_planes = split_planes(x_q, r_in, shift)
+        k_pad = (-k_dim) % bk
+        if k_pad:
+            x_planes = _pad_plane_k(x_planes, n_planes, k_dim, k_pad)
+            w_q = jnp.pad(w_q, ((0, 0), (0, k_pad), (0, 0)))
+        w_q = _pad_to(w_q.astype(jnp.int8), (1, 1, bn))
+        gamma3 = _pad_to(gamma[:, None, :].astype(jnp.float32), (1, 1, bn))
+        beta3 = _pad_to(beta[:, None, :].astype(jnp.float32), (1, 1, bn))
+    codes = cim_mbiw_grouped_planes(
+        x_planes, w_q, gamma3, beta3, block_group, block_src, block_live,
+        plane_shift=shift, g0=g0, r_out=r_out, bm=bm, bn=bn, bk=bk,
+        fuse_adc=fuse_adc)
+    with jax.named_scope("cim.recombine"):
+        return codes[:, :n]
 
 
 def cim_linear(x_q: jnp.ndarray, w_q: jnp.ndarray, gamma: jnp.ndarray,

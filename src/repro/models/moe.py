@@ -36,7 +36,8 @@ from jax.sharding import PartitionSpec as P, get_abstract_mesh
 from repro.core import abn as abn_lib
 from repro.core import mapping
 from repro.core import noise_model as nm
-from repro.core.cim_layers import CIMConfig, _code_gain, _engine_config
+from repro.core.cim_layers import (CIMConfig, _code_gain,
+                                   cim_grouped_dense_apply)
 from repro.core.quantization import (adc_quantize, quantize_act,
                                      quantize_weight, rounding_barrier)
 from repro.models.common import activation_fn
@@ -82,34 +83,23 @@ def _expert_gemm_engine(x_g: jnp.ndarray, w: jnp.ndarray, cim: CIMConfig,
                         abn: Optional[Tuple[jnp.ndarray, jnp.ndarray]],
                         key: Optional[jax.Array],
                         reference: bool) -> jnp.ndarray:
-    """(E, C, D) x (E, D, F) through ONE compiled CIM program, E binds.
+    """(E, C, D) x (E, D, F) in ONE grouped CIM call a row tile.
 
-    Every expert shares the same LayerSpec (capacity bucket, fan-in,
-    fan-out, precision) so compile_program returns a single cached
-    program; the per-expert weights/ABN differ only in the bind — the
-    plan-once/serve-many contract, visible as >= E serve calls per
-    program in CIMProgram.stats()."""
-    from repro.runtime.program import DEFAULT_BUCKETS, compile_program
-
+    Each expert's C capacity rows are one group: its own weights and ABN,
+    its own activation range over its C rows and, under noise, the draws
+    of fold_in(key, e) — the bits E separate one-layer programs would give
+    (cim_layers.cim_grouped_dense_apply)."""
     e, c, d = x_g.shape
     f = w.shape[2]
     # entry/exit barriers: match _expert_gemm's fakequant branch so the
     # digital glue around the expert GEMMs (activation, gating, scatter)
     # is the same isolated subgraph in both modes (rounding_barrier)
     x_g = rounding_barrier(x_g)
-    bucket = DEFAULT_BUCKETS.bucket_for(c)
-    spec = mapping.LayerSpec(m=bucket, k=d, n=f, r_in=cim.r_in,
-                             r_w=cim.r_w, r_out=cim.r_out)
-    prog = compile_program([spec], _engine_config(cim))
     lg, bt = _expert_abn(abn, e, f)
-    outs = []
-    for ei in range(e):
-        p = {"w": w[ei].astype(jnp.float32),
-             "abn_log_gamma": lg[ei], "abn_beta": bt[ei]}
-        sub = None if key is None else jax.random.fold_in(key, ei)
-        outs.append(prog.serve([p], x_g[ei].astype(jnp.float32), sub,
-                               reference=reference))
-    return rounding_barrier(jnp.stack(outs)).astype(x_g.dtype)
+    p = {"w": w.astype(jnp.float32), "abn_log_gamma": lg, "abn_beta": bt}
+    y = cim_grouped_dense_apply(p, x_g.astype(jnp.float32), cim, key=key,
+                                reference=reference)
+    return rounding_barrier(y).astype(x_g.dtype)
 
 
 def _expert_gemm(x_g: jnp.ndarray, w: jnp.ndarray, cim: CIMConfig,
@@ -309,3 +299,168 @@ def moe_block(params: Dict, x: jnp.ndarray, *, n_experts: int, top_k: int,
             )(xf, top_p, top_idx, w_gate, w_up,
               w_down, params["abn_log_gamma"], params["abn_beta"], key)
     return out.reshape(b, s, d), aux
+
+
+# ---------------------------------------------------------------------------
+# a dropless layer told which experts it holds (expert parallelism's share)
+# ---------------------------------------------------------------------------
+
+def init_moe_held(key: jax.Array, d: int, f: int, n_experts: int,
+                  held: int, shared_f: int = 0,
+                  cim: Optional[CIMConfig] = None) -> Dict:
+    """Router over all `n_experts` (D, E), the `held` experts' gate/up
+    (held, D, F) and down (held, F, D) banks as grouped CIM linears, and
+    the shared experts as one gated MLP of width `shared_f` (0: none)."""
+    from repro.core.cim_layers import init_cim_grouped
+    from repro.models.common import init_mlp
+    ks = jax.random.split(key, 5)
+    p = {"router": (1.0 / d) ** 0.5 * jax.random.normal(
+            ks[0], (d, n_experts), jnp.float32),
+         "w_gate": init_cim_grouped(ks[1], held, d, f, cim),
+         "w_up": init_cim_grouped(ks[2], held, d, f, cim),
+         "w_down": init_cim_grouped(ks[3], held, f, d, cim)}
+    if shared_f:
+        p["shared"] = init_mlp(ks[4], d, shared_f, True, cim)
+    return p
+
+
+def route_topk(x: jnp.ndarray, router: jnp.ndarray, top_k: int, *,
+               norm_topk: bool, scale: float
+               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Greedy softmax routing, digital: x (t, D) . router in float32 at
+    HIGHEST precision, softmax over every expert, the top k; weights
+    renormalised over the k when `norm_topk`, then times `scale`.
+    Returns (weights (t, k) float32, expert ids (t, k) int32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router,
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_idx = jax.lax.top_k(probs, top_k)
+    if norm_topk:
+        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    return top_p * scale, top_idx
+
+
+def held_layout(top_idx: jnp.ndarray, top_p: jnp.ndarray, *, held: int,
+                share: int, bm: int
+                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The grouped row layout of the routes to the held experts.
+
+    Every route (token, expert) with expert in [share*held,
+    (share+1)*held) gets one row, grouped by held expert in expert order,
+    each group from a multiple of `bm`.  The buffer holds every route
+    that can reach the held experts (each token routes to an expert at
+    most once), so no route is ever dropped.  Returns (row_group (M,),
+    row_token (M,), row_weight (M,)): a padding row has group `held`,
+    token 0 and weight 0."""
+    t, k = top_idx.shape
+    local = top_idx.reshape(-1) - share * held
+    mine = (local >= 0) & (local < held)
+    grp = jnp.where(mine, local, held).astype(jnp.int32)
+    wgt = jnp.where(mine, top_p.reshape(-1), 0.0)
+    tok = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
+    most = min(t * k, held * t)
+    rows = -(-(most + held * (bm - 1)) // bm) * bm
+    counts = jnp.zeros((held + 1,), jnp.int32).at[grp].add(1)
+    blocks = (counts[:held] + bm - 1) // bm
+    start = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                             jnp.cumsum(blocks) * bm])     # (held + 1,)
+    first = jnp.cumsum(counts) - counts                    # sorted offsets
+    order = jnp.argsort(grp, stable=True)
+    g_sorted = grp[order]
+    rank = jnp.arange(t * k, dtype=jnp.int32) - first[g_sorted]
+    dest = jnp.where(g_sorted < held, start[g_sorted] + rank, rows)
+    row_group = jnp.full((rows,), held, jnp.int32).at[dest].set(
+        g_sorted, mode="drop")
+    row_token = jnp.zeros((rows,), jnp.int32).at[dest].set(
+        tok[order], mode="drop")
+    row_weight = jnp.zeros((rows,), jnp.float32).at[dest].set(
+        wgt[order], mode="drop")
+    return row_group, row_token, row_weight
+
+
+# layout rows one grouped call serves: a longer layout (a prompt's) runs in
+# chunks of this many rows, its activation ranges taken over all of it
+CHUNK_ROWS = 16384
+
+
+def moe_held_block(params: Dict, x: jnp.ndarray, *, n_experts: int,
+                   top_k: int, held: int, share: int, norm_topk: bool,
+                   routed_scale: float, cim: CIMConfig, act: str = "silu"
+                   ) -> jnp.ndarray:
+    """x (B, S, D) -> (B, S, D): the part of a fine-grained MoE layer that
+    a chip holding experts [share*held, (share+1)*held) computes.
+
+    Routes over all `n_experts` (`route_topk`); routes to other experts
+    contribute nothing.  Dropless: every route to a held expert is
+    computed.  The routed rows are laid out by held expert
+    (`held_layout`), and each of the gate, up and down banks is one
+    grouped CIM call whose activation ranges are per expert, over the rows
+    routed to it (cim_layers.cim_grouped_apply).  A layout longer than
+    CHUNK_ROWS (a multiple of the layout's block) runs in chunks, the
+    ranges taken over the whole layout first, so its bits do not depend
+    on the chunking.  The shared experts' MLP is added once."""
+    from repro.core.cim_layers import cim_grouped_apply, group_block_rows
+    from repro.models.common import mlp_block
+    from repro.runtime.engine import group_ranges
+    b, s, d = x.shape
+    t = b * s
+    xf = x.reshape(t, d)
+    bm = group_block_rows(cim)
+    with jax.named_scope("lm.moe_route"):
+        top_p, top_idx = route_topk(xf, params["router"], top_k,
+                                    norm_topk=norm_topk, scale=routed_scale)
+    with jax.named_scope("lm.moe_dispatch"):
+        row_group, row_token, row_weight = held_layout(
+            top_idx, top_p, held=held, share=share, bm=bm)
+        m = row_group.shape[0]
+        chunk = min(m, CHUNK_ROWS)
+        n_chunks = -(-m // chunk)
+        pad = n_chunks * chunk - m
+        row_group = jnp.pad(row_group, (0, pad), constant_values=held)
+        row_token = jnp.pad(row_token, (0, pad))
+        row_weight = jnp.pad(row_weight, (0, pad))
+    fn = activation_fn(act)
+    ranged = n_chunks > 1 and cim.mode == "engine"
+
+    def ranges(rows_min, rows_max):
+        if not ranged:
+            return None
+        return group_ranges(rows_min, rows_max, row_group, held, cim.r_in)
+
+    r_x = ranges(jnp.min(xf, 1)[row_token], jnp.max(xf, 1)[row_token])
+
+    def hidden(rg, tok):
+        with jax.named_scope("lm.moe_dispatch"):
+            rows = xf[tok]
+        gate = cim_grouped_apply(params["w_gate"], rows, rg, cim, ranges=r_x)
+        up = cim_grouped_apply(params["w_up"], rows, rg, cim, ranges=r_x)
+        return fn(gate) * up
+
+    def combine(out, rg, tok, w, y):
+        with jax.named_scope("lm.moe_combine"):
+            dest = jnp.where(rg < held, tok, t)
+            return out.at[dest].add(y * w[:, None].astype(y.dtype),
+                                    mode="drop")
+
+    out = jnp.zeros((t, d), x.dtype)
+    if n_chunks == 1:
+        h = hidden(row_group, row_token)
+        y = cim_grouped_apply(params["w_down"], h, row_group, cim)
+        out = combine(out, row_group, row_token, row_weight, y)
+    else:
+        split = (row_group.reshape(n_chunks, chunk),
+                 row_token.reshape(n_chunks, chunk),
+                 row_weight.reshape(n_chunks, chunk))
+        h = jax.lax.map(lambda a: hidden(a[0], a[1]), split[:2])
+        r_h = ranges(jnp.min(h, 2).reshape(-1), jnp.max(h, 2).reshape(-1))
+
+        def down(acc, a):
+            rg, tok, w, hc = a
+            y = cim_grouped_apply(params["w_down"], hc, rg, cim, ranges=r_h)
+            return combine(acc, rg, tok, w, y), None
+
+        out, _ = jax.lax.scan(down, out, split + (h,))
+    out = out.reshape(b, s, d)
+    if "shared" in params:
+        out = out + mlp_block(params["shared"], x, cim, act)
+    return out

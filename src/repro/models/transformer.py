@@ -25,8 +25,10 @@ from repro.configs.base import ModelConfig
 from repro.core.cim_layers import CIMConfig, cim_linear_apply, init_cim_linear
 from repro.models import common as cm
 from repro.models import mamba2 as m2
+from repro.models import mla
 from repro.models import rglru as rg
-from repro.models.moe import init_moe, moe_block
+from repro.models.moe import (init_moe, init_moe_held, moe_block,
+                              moe_held_block)
 from repro.models.sharding import BATCH, TP, axis_size, shard
 
 
@@ -48,17 +50,26 @@ def _attn_cfg(cfg: ModelConfig, *, window: int = 0, causal: bool = True,
 # layer init (one layer; stacked via vmap over keys)
 # ---------------------------------------------------------------------------
 
-def _init_decoder_layer(cfg: ModelConfig, key: jax.Array) -> Dict:
+def _init_decoder_layer(cfg: ModelConfig, key: jax.Array,
+                        dense: bool = False) -> Dict:
+    """One decoder layer; `dense` gives a MoE stack's leading layers
+    their dense FFN (width d_ff)."""
     ks = jax.random.split(key, 4)
     cim = cfg.cim
     p: Dict[str, Any] = {
         "ln1": cm.init_norm(cfg.d_model, cfg.norm_type),
         "ln2": cm.init_norm(cfg.d_model, cfg.norm_type),
-        "attn": cm.init_attention(
-            ks[0], _attn_cfg(cfg, window=cfg.sliding_window), cim),
+        "attn": (mla.init_mla(ks[0], cfg, cim)
+                 if cfg.kv_lora_rank else cm.init_attention(
+                     ks[0], _attn_cfg(cfg, window=cfg.sliding_window), cim)),
     }
-    if cfg.family == "moe":
-        p["moe"] = init_moe(ks[1], cfg.d_model, cfg.d_ff, cfg.moe_experts, cim)
+    if cfg.family == "moe" and not dense and cfg.moe_held:
+        p["moe"] = init_moe_held(ks[1], cfg.d_model, cfg.moe_d_ff or cfg.d_ff,
+                                 cfg.moe_experts, cfg.moe_held,
+                                 cfg.moe_shared_d_ff, cim)
+    elif cfg.family == "moe" and not dense:
+        p["moe"] = init_moe(ks[1], cfg.d_model, cfg.moe_d_ff or cfg.d_ff,
+                            cfg.moe_experts, cim)
     else:
         p["mlp"] = cm.init_mlp(ks[1], cfg.d_model, cfg.d_ff, cfg.gated_mlp, cim)
     return p
@@ -136,9 +147,13 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict:
         params["lm_head"] = init_cim_linear(keys[1], d, cfg.vocab_size)
 
     if cfg.family in ("dense", "moe", "vlm"):
-        lk = jax.random.split(keys[2], cfg.n_layers)
+        lk = jax.random.split(keys[2], cfg.n_layers - cfg.dense_layers)
         params["layers"] = jax.vmap(
             functools.partial(_init_decoder_layer, cfg))(lk)
+        if cfg.dense_layers:
+            dk = jax.random.split(keys[5], cfg.dense_layers)
+            params["dense_layers"] = jax.vmap(functools.partial(
+                _init_decoder_layer, cfg, dense=True))(dk)
     elif cfg.family == "ssm":
         lk = jax.random.split(keys[2], cfg.n_layers)
         params["layers"] = jax.vmap(
@@ -179,23 +194,36 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict:
 
 def _decoder_layer(cfg: ModelConfig, p: Dict, x: jnp.ndarray, *,
                    positions: jnp.ndarray, cache: Optional[Dict],
-                   key: Optional[jax.Array] = None
+                   key: Optional[jax.Array] = None, dense: bool = False
                    ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
     """One pre-norm decoder layer (attention + MLP-or-MoE FFN); `key`
     seeds the CIM noise model of the layer's projections (distinct folds
-    for the attention and FFN banks)."""
+    for the attention and FFN banks).  `dense` marks a MoE stack's
+    leading dense layer."""
     cim = cfg.cim
     k_attn = k_ffn = None
     if key is not None:
         k_attn, k_ffn = jax.random.fold_in(key, 0), jax.random.fold_in(key, 1)
     h = cm.apply_norm(p["ln1"], x, cfg.norm_type)
-    attn_out, new_kv = cm.attention_block(
-        p["attn"], h, _attn_cfg(cfg, window=cfg.sliding_window), cim,
-        positions=positions, cache=None if cache is None else cache["kv"],
-        key=k_attn)
+    kv = None if cache is None else cache["kv"]
+    if cfg.kv_lora_rank:
+        attn_out, new_kv = mla.mla_block(
+            p["attn"], h, cfg, cim, positions=positions,
+            cache=kv)
+    else:
+        attn_out, new_kv = cm.attention_block(
+            p["attn"], h, _attn_cfg(cfg, window=cfg.sliding_window), cim,
+            positions=positions, cache=kv, key=k_attn)
     x = x + attn_out
     h = cm.apply_norm(p["ln2"], x, cfg.norm_type)
-    if cfg.family == "moe":
+    if cfg.family == "moe" and not dense and cfg.moe_held:
+        ffn_out = moe_held_block(
+            p["moe"], h, n_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
+            held=cfg.moe_held, share=cfg.moe_share,
+            norm_topk=cfg.moe_norm_topk, routed_scale=cfg.moe_routed_scale,
+            cim=cim, act=cfg.mlp_act)
+        aux = 0.0
+    elif cfg.family == "moe" and not dense:
         ffn_out, aux = moe_block(
             p["moe"], h, n_experts=cfg.moe_experts, top_k=cfg.moe_top_k,
             capacity_factor=cfg.moe_capacity_factor, cim=cim, act=cfg.mlp_act,
@@ -270,6 +298,28 @@ def _scan_stack(layer_fn, stacked_params, x, cache, remat: bool,
 def _decoder_stack(cfg: ModelConfig, params, x, positions, cache, key=None):
     layer = {"dense": _decoder_layer, "moe": _decoder_layer,
              "vlm": _decoder_layer, "ssm": _ssm_layer}[cfg.family]
+
+    if cfg.dense_layers:
+        # the leading dense layers, then the scanned MoE stack; the cache
+        # holds one stack of each
+        def f_dense(p, x, c):
+            return layer(cfg, p, x, positions=positions, cache=c,
+                         dense=True)
+
+        def f_moe(p, x, c):
+            return layer(cfg, p, x, positions=positions, cache=c)
+
+        x, new_dense, aux0 = _scan_stack(
+            f_dense, params["dense_layers"], x,
+            None if cache is None else cache["dense"], cfg.remat,
+            cfg.remat_policy)
+        x, new_moe, aux = _scan_stack(
+            f_moe, params["layers"], x,
+            None if cache is None else cache["moe"], cfg.remat,
+            cfg.remat_policy)
+        new_cache = (None if cache is None
+                     else {"dense": new_dense, "moe": new_moe})
+        return x, new_cache, aux0 + aux
 
     if key is None:
         def f(p, x, c):
@@ -367,6 +417,10 @@ def forward(cfg: ModelConfig, params, tokens: jnp.ndarray, *,
     if key is not None and cfg.family not in ("dense", "moe", "vlm"):
         raise ValueError(
             f"noise-keyed forward is not wired for family {cfg.family!r}")
+    if key is not None and (cfg.kv_lora_rank or cfg.moe_held
+                            or cfg.dense_layers):
+        raise ValueError("noise-keyed forward is not wired for latent "
+                         "attention, held-expert or dense-prefix stacks")
     b, s = tokens.shape
     x = embed_tokens(cfg, params, tokens)
 
@@ -481,6 +535,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
         return stack(cm.init_kv_cache(batch, length, g, hd, dtype), n)
 
     pos = jnp.array(0, jnp.int32)
+    if cfg.family in ("dense", "moe", "vlm") and cfg.kv_lora_rank:
+        # latent attention: c_kv and k_pe a token a layer, never per head
+        def latent(n):
+            return stack(mla.init_latent_cache(batch, max_len, cfg, dtype),
+                         n)
+
+        if not cfg.dense_layers:
+            return {"pos": pos, "layers": {"kv": latent(cfg.n_layers)}}
+        return {"pos": pos, "layers": {
+            "dense": {"kv": latent(cfg.dense_layers)},
+            "moe": {"kv": latent(cfg.n_layers - cfg.dense_layers)}}}
     if cfg.family in ("dense", "moe", "vlm"):
         length = _kv_cache_len(cfg, max_len, cfg.sliding_window)
         return {"pos": pos, "layers": {"kv": kv(cfg.n_layers, length)}}
@@ -516,10 +581,11 @@ def init_slot_cache(cfg: ModelConfig, slots: int, max_len: int,
     cm.init_slot_kv_cache} — every slot rides its own ring cursor, so
     requests at different sequence offsets decode fused in one batch.
     Attention-cache families only (dense/moe/vlm)."""
-    if cfg.family not in ("dense", "moe", "vlm"):
+    if cfg.family not in ("dense", "moe", "vlm") or cfg.kv_lora_rank \
+            or cfg.dense_layers:
         raise ValueError(
-            f"slot-mapped decode supports attention-cache families "
-            f"(dense/moe/vlm), not {cfg.family!r}")
+            f"slot-mapped decode supports the per-head KV caches of "
+            f"dense/moe/vlm stacks, not {cfg.name!r}")
     hd = cfg.resolved_head_dim
     length = _kv_cache_len(cfg, max_len, cfg.sliding_window)
     kvs = jax.tree.map(

@@ -16,6 +16,15 @@ call per row tile over all of a device's col tiles.
     y = engine(params, x)                        # jit-compiled schedule
     y_ref = engine.reference(params, x)          # pure-jnp digital oracle
 
+Grouped calls: `grouped_layer` runs one layer shape over G weight sets
+(the experts a chip holds, MLA's absorbed per-head products) as one
+kernel call a row tile.  Rows are laid out in blocks of `group_block_rows`
+rows of one group each; the kernel (`cim_mbiw`, the same Pallas name)
+takes each block's group by scalar prefetch, so the weight block follows
+the group and an empty block computes nothing.  Activation ranges are per
+group over its live rows, so a group's rows get the bits of one ungrouped
+call over them.
+
 Plan-once/serve-many: the deployment API lives in runtime/program.py —
 `compile_program(specs, cfg)` returns an immutable `CIMProgram` (a
 NetworkPlan plus an executable cache keyed on batch bucket, noise mode and
@@ -112,13 +121,17 @@ carries the scope; an op belongs to the innermost taxonomy scope there):
   cim.recombine   dequant/accumulate of codes, stream-chunk concat
   cim.epilogue    scale multiply, activation, max-pool, reshape back
   cim.noise       noise fields and the noisy ADC epilogue
-  lm.embed, lm.norm, lm.attention (RoPE, scores, softmax, PV),
-  lm.kv_write (the KV cache update), lm.head (logits, argmax),
+  lm.embed, lm.norm, lm.attention (RoPE, scores, softmax, PV; MLA's
+  absorbed products are cim.* inside it), lm.kv_write (the KV or latent
+  cache update), lm.head (logits, argmax), lm.moe_route (router logits,
+  softmax, top-k), lm.moe_dispatch (the held experts' row layout and its
+  gather), lm.moe_combine (weighted scatter-add of the experts' rows),
   lm.layer (the rest of a decoder layer: residuals, SwiGLU glue)
 
-The `lm.*` scopes live in models/common.py, models/transformer.py and
-launch/steps.py.  A scope is compile-time metadata: it changes no
-computation, no trace count and no compile, and is always on.  Renaming
+The `lm.*` scopes live in models/common.py, models/mla.py, models/moe.py,
+models/transformer.py and launch/steps.py.  A scope is compile-time
+metadata: it changes no computation, no trace count and no compile, and
+is always on.  Renaming
 one renames a benchmark reading (bench/scopes.py sums scopes by these
 names).  `CIMProgram`'s bucketed dispatch runs inside the host span
 `repro.serve` (runtime/program.py).
@@ -988,6 +1001,220 @@ def _forward(plan: NetworkPlan, binds: Sequence[Dict[str, jnp.ndarray]],
                         nids=nids)
     with jax.named_scope("cim.epilogue"):
         return xc.reshape(lead + xc.shape[1:])
+
+
+# ---------------------------------------------------------------------------
+# grouped calls: one layer shape, G weight sets, one kernel call a row tile
+# ---------------------------------------------------------------------------
+
+def group_block_rows(cfg: EngineConfig) -> int:
+    """Rows of one block of a grouped call's layout: every block of this
+    many GEMM rows belongs to one group (the kernel's row block)."""
+    return cfg.bm
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_plan(spec: mapping.LayerSpec, cfg: EngineConfig) -> LayerPlan:
+    return plan_layer(spec, cfg)
+
+
+def _block_vectors(row_group: jnp.ndarray, n_groups: int, bm: int):
+    """(block_group, block_src, block_live) of a grouped row layout.
+
+    A block is live when its first row is (a group's rows start at a block
+    boundary and fill it from the top).  An empty block takes the group
+    and the input block of the nearest live block before it, so the kernel
+    fetches no new operand for it."""
+    first = row_group.reshape(-1, bm)[:, 0]
+    live = first < n_groups
+    idx = jnp.arange(first.shape[0], dtype=jnp.int32)
+    src = jnp.maximum(jax.lax.cummax(jnp.where(live, idx, -1), axis=0), 0)
+    grp = jnp.minimum(first[src], n_groups - 1)
+    return grp, src, live.astype(jnp.int32)
+
+
+def _grouped_tile_schedule(lp: LayerPlan, q_rows: jnp.ndarray,
+                           zp_blk: jnp.ndarray, wqq: jnp.ndarray,
+                           gamma: jnp.ndarray, beta: jnp.ndarray,
+                           blocks: Tuple[jnp.ndarray, ...], bm: int, *,
+                           matmul, nctx: Optional[_LayerNoise] = None
+                           ) -> jnp.ndarray:
+    """`_tile_schedule` over a grouped layout: `wqq` (G, K, n_pad),
+    `gamma`/`beta` (G, n_pad), `zp_blk` (B,) the zero-point of each row
+    block's group.  Every per-column expression is `_tile_schedule`'s,
+    applied with the row block's group parameters, so a group's rows get
+    the bits one ungrouped call over them would.  `nctx` holds per-block
+    offsets (B, 1, n_pad) and the thermal field per row tile laid out
+    over the rows (KT, B, bm, n_pad)."""
+    grp = blocks[0]
+    n_blocks = grp.shape[0]
+    mid = 2.0 ** (lp.spec.r_out - 1)
+    g0 = lp.g0
+    with jax.named_scope("cim.recombine"):
+        gain = rounding_barrier(gamma * g0)
+        gain_b, beta_b, gamma_b = gain[grp], beta[grp], gamma[grp]
+        acc = jnp.zeros((n_blocks, bm, wqq.shape[2]), jnp.float32)
+    for ki, (ks, ksz) in enumerate(lp.k_slices):
+        ke = ks + ksz
+        with jax.named_scope("cim.zp_fold"):
+            zp_dp = zp_blk[:, None] * jnp.sum(wqq[:, ks:ke], axis=1)[grp]
+            beta_eff = beta_b + rounding_barrier(gain_b * zp_dp)
+        with jax.named_scope("cim.planes"):
+            out = matmul(q_rows[:, ks:ke], wqq[:, ks:ke], gamma_b,
+                         beta_eff, g0, *blocks)
+        out = out.reshape(n_blocks, bm, -1)
+        if nctx is None:
+            codes = out
+        else:
+            with jax.named_scope("cim.noise"):
+                codes = _noise_adc_code(lp, out, gamma_b[:, None],
+                                        beta_eff[:, None], nctx,
+                                        nctx.thermal[ki])
+        with jax.named_scope("cim.recombine"):
+            acc = acc + (codes.astype(jnp.float32) + 0.5 - mid
+                         - beta_b[:, None]) / gain_b[:, None]
+    return acc.reshape(n_blocks * bm, -1)
+
+
+def _grouped_noise(lp: LayerPlan, cfg: EngineConfig, noise: NoiseConfig,
+                   gamma_p: jnp.ndarray, key: jax.Array, group_rows: int,
+                   bm: int) -> _LayerNoise:
+    """Per-group noise contexts, laid out over a grouped call of equal
+    groups (`group_rows` live rows each, padded to whole blocks): group g
+    draws what its own one-layer program would with key fold_in(key, g)."""
+    r_pad = -(-group_rows // bm) * bm
+    ctx = [_layer_noise(lp, cfg, noise, gamma_p[g],
+                        jax.random.fold_in(jax.random.fold_in(key, g), 0),
+                        group_rows)
+           for g in range(gamma_p.shape[0])]
+    per = r_pad // bm
+
+    def blocks(v):                       # (G, n_pad) -> (B, 1, n_pad)
+        return jnp.repeat(jnp.stack(v), per, axis=0)[:, None, :]
+
+    # (G, KT, NT, R, tile_n) -> per row tile (B, bm, NT * tile_n)
+    th = jnp.stack([c.thermal for c in ctx])
+    g, kt, nt, r, tn = th.shape
+    th = jnp.transpose(th, (1, 0, 3, 2, 4)).reshape(kt, g, r, nt * tn)
+    th = _pad_dim(th, 2, r_pad).reshape(kt, g * per, bm, nt * tn)
+    return _LayerNoise(offset_codes=blocks([c.offset_codes for c in ctx]),
+                       droop_codes=blocks([c.droop_codes for c in ctx]),
+                       gain_mult=ctx[0].gain_mult, thermal=th)
+
+
+def _grouped_kernel_matmul(lp: LayerPlan, cfg: EngineConfig, bm: int):
+    fuse = not cfg.noise.enabled
+
+    def matmul(xq, wq, gamma_b, beta_b, g0, grp, src, live):
+        return kops.cim_matmul_grouped(
+            xq, wq, gamma_b, beta_b, grp, src, live, r_in=lp.spec.r_in,
+            r_out=lp.spec.r_out, g0=g0, bm=bm, bn=cfg.bn, bk=cfg.bk,
+            fuse_adc=fuse)
+    return matmul
+
+
+def _grouped_reference_matmul(lp: LayerPlan, cfg: EngineConfig, bm: int):
+    from repro.kernels.cim_mbiw.ref import cim_matmul_ref
+
+    def matmul(xq, wq, gamma_b, beta_b, g0, grp, src, live):
+        xb = xq.reshape(grp.shape[0], bm, -1)
+        wb = wq[grp]
+        if cfg.noise.enabled:
+            out = jnp.einsum("bmk,bkn->bmn", xb.astype(jnp.int32),
+                             wb.astype(jnp.int32))
+        else:
+            out = jax.vmap(lambda x, w, g, b: cim_matmul_ref(
+                x, w, g, b, g0=g0, r_out=lp.spec.r_out))(
+                    xb, wb, gamma_b, beta_b)
+        out = jnp.where(live[:, None, None] != 0, out, 0)
+        return out.reshape(xq.shape[0], -1)
+    return matmul
+
+
+def group_ranges(row_min: jnp.ndarray, row_max: jnp.ndarray,
+                 row_group: jnp.ndarray, n_groups: int, r_in: int,
+                 eps: float = 1e-8) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(zero, scale), each (G + 1,): the activation range of every group
+    of a grouped layout from its rows' minima and maxima, exactly as
+    quantize_act's segment path takes it (padding rows, group G, form a
+    segment of their own)."""
+    from repro.core.quantization import _static_reciprocal
+    with jax.named_scope("cim.act_quant"):
+        lo = jax.ops.segment_min(row_min, row_group,
+                                 num_segments=n_groups + 1)
+        hi = jax.ops.segment_max(row_max, row_group,
+                                 num_segments=n_groups + 1)
+        scale = jnp.maximum(hi - lo, eps) * _static_reciprocal(
+            2.0 ** r_in - 1.0)
+        return lo, scale
+
+
+def grouped_layer(spec: mapping.LayerSpec, cfg: EngineConfig, params,
+                  x: jnp.ndarray, row_group: jnp.ndarray, *,
+                  ranges: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
+                  reference: bool = False, key: Optional[jax.Array] = None,
+                  group_rows: int = 0) -> jnp.ndarray:
+    """One layer shape over G weight sets in one kernel call a row tile.
+
+    Args:
+      spec: the (K, N) layer and its precision (spec.m is not used).
+      cfg: execution config; its sharding is not used (a grouped call runs
+        on one device).
+      params: {"w" (G, K, N), "abn_log_gamma" (G, N), "abn_beta" (G, N)}.
+      x: (M, K) float rows, M a multiple of `group_block_rows(cfg)`, laid
+        out in blocks of one group each; a group's rows start at a block
+        boundary.
+      row_group: (M,) int32, each row's group, or G for a padding row.
+      ranges: optional (zero, scale), each (G + 1,), the groups'
+        activation ranges (`group_ranges`) when they span more rows than
+        this call holds: a layout served in chunks quantizes every chunk
+        as the whole layout would.  None takes them over x's rows.
+      reference: run the pure-jnp oracle of the same schedule.
+      key, group_rows: the noise model's key (required when cfg.noise is
+        on) and the live rows of every group; noise needs equal groups,
+        each `group_rows` rows from its first block.
+    Returns:
+      (M, N) float32.  Activation ranges are taken per group over its live
+      rows only (quantize_act segments; padding rows form a segment of
+      their own), so each group's rows get the bits of one ungrouped call
+      over them; a padding row's output is meaningless.
+    """
+    from repro.core.quantization import quantize_act
+    lp = _grouped_plan(spec, cfg.replace(sharding=None))
+    n_groups = params["w"].shape[0]
+    bm = group_block_rows(cfg)
+    noise = cfg.noise if cfg.noise.enabled else None
+    if noise is not None and (key is None or group_rows < 1):
+        raise ValueError("a noisy grouped call needs a key and equal "
+                         "groups (group_rows)")
+    binds = jax.vmap(lambda p: bind_layer(lp, p, cfg))(params)
+    blocks = _block_vectors(row_group, n_groups, bm)
+    with jax.named_scope("cim.act_quant"):
+        if ranges is None:
+            aq = quantize_act(x.astype(jnp.float32), spec.r_in,
+                              segment_ids=row_group,
+                              num_segments=n_groups + 1)
+        else:
+            zero, scale = ranges
+            aq = quantize_act(x.astype(jnp.float32), spec.r_in,
+                              zero=zero[row_group][:, None],
+                              scale=scale[row_group][:, None])
+        zp = jnp.asarray(aq.zero / aq.scale, jnp.float32)
+        # a live block's first row is live: its zero-point is the group's
+        zp_blk = zp.reshape(-1, bm)[:, 0]
+    with jax.named_scope("cim.noise"):
+        nctx = (_grouped_noise(lp, cfg, noise, binds["gamma_p"], key,
+                               group_rows, bm)
+                if noise is not None else None)
+    mk = _grouped_reference_matmul if reference else _grouped_kernel_matmul
+    dp_hat = _grouped_tile_schedule(
+        lp, aq.q, zp_blk, binds["wqq"], binds["gamma_p"], binds["beta_p"],
+        blocks, bm, matmul=mk(lp, cfg, bm), nctx=nctx)
+    with jax.named_scope("cim.epilogue"):
+        n, nb = spec.n, blocks[0].shape[0]
+        y = (dp_hat[:, :n].reshape(nb, bm, n) * aq.scale.reshape(nb, bm, 1)
+             * binds["w_scale"][blocks[0]][:, None, :])
+        return y.reshape(nb * bm, n)
 
 
 @functools.partial(jax.jit, static_argnames=("plan", "bound", "reference"))

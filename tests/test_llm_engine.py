@@ -21,13 +21,11 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.core.cim_layers import CIMConfig, _engine_config
+from repro.core.cim_layers import CIMConfig
 from repro.core.noise_model import NoiseConfig
-from repro.core import mapping
 from repro.models import transformer as tf
 from repro.models.moe import init_moe, moe_block
 from repro.runtime import engine as rt
-from repro.runtime.program import DEFAULT_BUCKETS, compile_program
 
 N_DEV = len(jax.devices())
 GRID = [(r_in, r_w) for r_in in (1, 2, 4, 8) for r_w in (1, 2, 4)]
@@ -94,29 +92,37 @@ def test_moe_unknown_cim_mode_raises():
                   cim=cim.replace(mode="sim"))
 
 
-def test_moe_engine_program_reuse_is_expertfold():
-    """E experts route through ONE cached program per GEMM shape: after a
-    moe_block call, the (d->f) program has >= 2E serve calls (gate+up
-    banks) and the (f->d) program >= E — the plan-once/serve-many
-    contract of CIMProgram.stats()."""
-    params, x, _, en_cim = _moe_pair(4, 2)
-    e, d, f = 4, 16, 48
-    t = x.shape[0] * x.shape[1]
-    cap = max(8, min(int(1.25 * 2 * t / e + 0.5), t * 2))
-    spec_up = mapping.LayerSpec(m=DEFAULT_BUCKETS.bucket_for(cap), k=d, n=f,
-                                r_in=4, r_w=2, r_out=en_cim.r_out)
-    spec_dn = mapping.LayerSpec(m=DEFAULT_BUCKETS.bucket_for(cap), k=f, n=d,
-                                r_in=4, r_w=2, r_out=en_cim.r_out)
-    prog_up = compile_program([spec_up], _engine_config(en_cim))
-    prog_dn = compile_program([spec_dn], _engine_config(en_cim))
-    up0 = prog_up.stats()["serve_calls"]
-    dn0 = prog_dn.stats()["serve_calls"]
-    moe_block(params, x, n_experts=e, top_k=2, capacity_factor=1.25,
-              cim=en_cim)
-    # gate and up share the (d->f) spec: one program, 2E binds served
-    assert prog_up.stats()["serve_calls"] - up0 >= 2 * e
-    assert prog_dn.stats()["serve_calls"] - dn0 >= e
-    assert prog_up.stats()["plans_built"] == 1
+def _kernel_calls(jaxpr) -> int:
+    """`cim_mbiw` dispatches in a jaxpr (the TPU branch of each)."""
+    from jax.extend import core as jcore
+    n = 0
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == "cim_mbiw"
+                and not eqn.params["interpret"]):
+            n += 1
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    n += _kernel_calls(sub.jaxpr)
+                elif isinstance(sub, jcore.Jaxpr):
+                    n += _kernel_calls(sub)
+    return n
+
+
+@pytest.mark.parametrize("e", [4, 8])
+def test_moe_engine_program_reuse_is_expertfold(e):
+    """E experts share ONE grouped CIM call per bank: a moe_block traces
+    one `cim_mbiw` dispatch per (bank, row tile) — gate, up and down are
+    one row tile each at d=16, f=48 — however many experts it has, where
+    a call per expert would trace 3E."""
+    cim = CIMConfig(mode="engine", r_in=4, r_w=2)
+    params = init_moe(jax.random.PRNGKey(5), 16, 48, e)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 8, 16), jnp.float32)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        moe_block, n_experts=e, top_k=2, capacity_factor=1.25,
+        cim=cim))(params, x)
+    assert _kernel_calls(jaxpr.jaxpr) == 3
 
 
 @pytest.mark.parametrize("r_in,r_w", GRID)

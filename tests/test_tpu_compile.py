@@ -146,3 +146,33 @@ def test_cim_kernel_call_carries_its_scope(one_chip):
     for line in calls:
         op_name = re.search(r'op_name="([^"]*)"', line).group(1)
         assert "/cim.kernel/" in op_name, op_name
+
+
+# grouped calls at dsv2lite.decode.b256's shapes, one row tile each: the 8
+# held experts' gate/up (2048 -> 1408, row tile K 1024) over the dropless
+# layout of 256 x 6 routes in 20 blocks of 128 rows, and the 16 heads'
+# absorbed q_lat product (128 -> 512) over 256 rows a head
+GROUPED_CALLS = [(8, 20, 1024, 1408), (16, 32, 128, 512)]
+
+
+@pytest.mark.parametrize("groups,blocks,k,n", GROUPED_CALLS,
+                         ids=["held_experts", "absorbed_heads"])
+def test_cim_mbiw_grouped_call_compiles(one_chip, groups, blocks, k, n):
+    from repro.runtime.engine import EngineConfig
+
+    cfg = EngineConfig()
+    rows = blocks * cfg.bm
+
+    def shape(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+    def call(x, w, g, b, grp, src, live):
+        return ops.cim_matmul_grouped(x, w, g, b, grp, src, live, r_in=8,
+                                      r_out=8, g0=0.01, bm=cfg.bm,
+                                      bn=cfg.bn, bk=cfg.bk)
+
+    idx = shape((blocks,), jnp.int32)
+    _compiled_text(call, shape((rows, k), jnp.float32),
+                   shape((groups, k, n), jnp.float32),
+                   shape((blocks, n), jnp.float32),
+                   shape((blocks, n), jnp.float32), idx, idx, idx)
