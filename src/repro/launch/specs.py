@@ -188,8 +188,12 @@ def batch_specs(inputs: Dict[str, Any], mesh) -> Dict[str, Any]:
         p = _path_to_str(path)
         nd = len(leaf.shape)
         if p.startswith("cache"):
-            if re.search(r"/k$|/v$", p) and nd == 5:
-                # (L, B, S, G, hd): seq-sharded over model (DESIGN.md §5)
+            if re.search(r"/kv/[kv]$", p) and nd == 5:
+                # ring cache (L, S, B, G, hd): seq-sharded over model
+                # (DESIGN.md §5)
+                sp = P(None, "model", ba, None, None)
+            elif re.search(r"/xkv/[kv]$", p) and nd == 5:
+                # cross K/V (L, B, S, G, hd)
                 sp = P(None, ba, "model", None, None)
             elif re.search(r"/ssm$", p) and nd == 5:
                 sp = P(None, ba, "model", None, None)

@@ -14,6 +14,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core.cim_layers import CIMConfig, cim_linear_apply, init_cim_linear
 from repro.models.sharding import BATCH, TP, shard
@@ -223,6 +224,7 @@ def _repeat_kv_to(x: jnp.ndarray, target_heads: int) -> jnp.ndarray:
 def attention_block(params: Dict, x: jnp.ndarray, cfg: AttnConfig,
                     cim: CIMConfig, *, positions: jnp.ndarray,
                     cache: Optional[Dict] = None,
+                    layer=0,
                     kv_repeat_to: int = 0,
                     x_kv: Optional[jnp.ndarray] = None,
                     cross_kv: Optional[Dict] = None,
@@ -232,6 +234,10 @@ def attention_block(params: Dict, x: jnp.ndarray, cfg: AttnConfig,
     """Self- (x_kv None) or cross- (x_kv given) attention with optional
     KV cache for decode.  `cross_kv` supplies precomputed cross-attention
     K/V ({"k","v"}) during cached decode.  Returns (out, updated_cache).
+
+    A self-attention `cache` is the whole layer stack's (init_kv_cache);
+    `layer` (an int or a traced index) picks this layer's slice, which is
+    written in place and read back without a copy of the stack.
 
     `key` seeds the CIM noise model of the four projections (a distinct
     fold per projection); None keeps them clean/deterministic.
@@ -282,53 +288,48 @@ def attention_block(params: Dict, x: jnp.ndarray, cfg: AttnConfig,
                 v = _repeat_kv_to(v, kv_repeat_to)
             if use_pallas:
                 pass  # shard_map in_specs drive k/v layout (replicated on TP)
-            if cache is not None and x_kv is None and cache["idx"].ndim == 1:
-                # slot-mapped decode (in-flight batching): `idx` is a (B,)
-                # per-slot write cursor, every batch row rides its own ring
-                # position.  Scatter-write one token per row; the mask
-                # positions become per-row (B, L) and plain_attention builds
-                # the keep-mask per batch row.
-                if s != 1:
-                    raise ValueError(
-                        f"slot-mapped KV decode is single-token (s=1), got "
-                        f"s={s}; prefill per request and scatter into the "
-                        "slot with write_slot_kv")
+            if cache is not None and x_kv is None:
+                # decode: the stacked ring cache (n_layers, L, B, g, hd)
+                # rides the layer scan's carry; this layer's s rows land at
+                # (layer, idx % L) in place (s == 1 for decode; a prompt
+                # prefilled into the cache needs idx + s <= L).  `idx` is
+                # the layer's shared cursor, or a (B,) per-slot cursor of a
+                # slot-mapped cache (in-flight batching), whose rows each
+                # ride their own ring position and get per-row masks.
                 length = cache["k"].shape[1]
-                idx = cache["idx"]
+                idx = cache["idx"][layer]
                 with jax.named_scope("lm.kv_write"):
                     write = jax.lax.rem(idx, length)
-                    rows = jnp.arange(b)
-                    k = cache["k"].at[rows, write].set(
-                        k[:, 0].astype(cache["k"].dtype))
-                    v = cache["v"].at[rows, write].set(
-                        v[:, 0].astype(cache["v"].dtype))
-                    k = shard(k, BATCH, TP, None, None)
-                    v = shard(v, BATCH, TP, None, None)
-                new_cache = {"k": k, "v": v, "idx": idx + s}
-                # position held by ring slot j after the write, per batch row
-                j = jnp.arange(length)[None, :]
-                last = (idx + s - 1)[:, None]
-                src_pos = last - jnp.mod(last - j, length)
-                src_pos = jnp.where(src_pos >= 0, src_pos, -10**9)
-            elif cache is not None and x_kv is None:
-                # decode: ring-buffer append at idx % L (s == 1 for decode;
-                # multi-token prefill-into-cache requires idx + s <= L)
-                length = cache["k"].shape[1]
-                idx = cache["idx"]
-                with jax.named_scope("lm.kv_write"):
-                    write = jax.lax.rem(idx, length)
-                    k = jax.lax.dynamic_update_slice(
-                        cache["k"], k.astype(cache["k"].dtype),
-                        (0, write, 0, 0))
-                    v = jax.lax.dynamic_update_slice(
-                        cache["v"], v.astype(cache["v"].dtype),
-                        (0, write, 0, 0))
-                    k = shard(k, BATCH, TP, None, None)
-                    v = shard(v, BATCH, TP, None, None)
-                new_cache = {"k": k, "v": v, "idx": idx + s}
-                # position held by ring slot j after the write
+                    kt = jnp.swapaxes(k, 0, 1).astype(cache["k"].dtype)
+                    vt = jnp.swapaxes(v, 0, 1).astype(cache["v"].dtype)
+                    if idx.ndim:
+                        if s != 1:
+                            raise ValueError(
+                                f"slot-mapped KV decode is single-token "
+                                f"(s=1), got s={s}; prefill per request and "
+                                "scatter into the slot with write_slot_kv")
+                        rows = jnp.arange(b)
+                        k_all = cache["k"].at[layer, write, rows].set(kt[0])
+                        v_all = cache["v"].at[layer, write, rows].set(vt[0])
+                    else:
+                        at = (layer, write, 0, 0, 0)
+                        k_all = jax.lax.dynamic_update_slice(
+                            cache["k"], kt[None], at)
+                        v_all = jax.lax.dynamic_update_slice(
+                            cache["v"], vt[None], at)
+                    k_all = shard(k_all, None, TP, BATCH, None, None)
+                    v_all = shard(v_all, None, TP, BATCH, None, None)
+                    k_all, v_all = carried(k_all), carried(v_all)
+                    new_cache = {"k": k_all, "v": v_all,
+                                 "idx": cache["idx"].at[layer].add(s)}
+                k = jnp.swapaxes(jax.lax.dynamic_index_in_dim(
+                    k_all, layer, keepdims=False), 0, 1)
+                v = jnp.swapaxes(jax.lax.dynamic_index_in_dim(
+                    v_all, layer, keepdims=False), 0, 1)
+                # position held by ring slot j after the write (per batch
+                # row for a per-slot cursor)
                 j = jnp.arange(length)
-                last = idx + s - 1
+                last = (idx + s - 1)[..., None]
                 src_pos = last - jnp.mod(last - j, length)
                 src_pos = jnp.where(src_pos >= 0, src_pos, -10**9)
             elif cache is not None:
@@ -365,54 +366,90 @@ def attention_block(params: Dict, x: jnp.ndarray, cfg: AttnConfig,
         return shard(y, BATCH, None, None), new_cache
 
 
-def init_kv_cache(batch: int, max_len: int, n_kv: int, head_dim: int,
-                  dtype=jnp.bfloat16) -> Dict:
-    """Ring-buffer decode cache with one shared write cursor (all batch
-    rows advance in lockstep — the classic static-batch serving shape)."""
-    return {"k": jnp.zeros((batch, max_len, n_kv, head_dim), dtype),
-            "v": jnp.zeros((batch, max_len, n_kv, head_dim), dtype),
-            "idx": jnp.array(0, jnp.int32)}
+def init_kv_cache(n_layers: int, batch: int, max_len: int, n_kv: int,
+                  head_dim: int, dtype=jnp.bfloat16) -> Dict:
+    """Ring-buffer decode cache of a layer stack with one shared write
+    cursor a layer (all batch rows advance in lockstep — the classic
+    static-batch serving shape).
+
+    K and V are stored position-major, (n_layers, L, B, g, hd): a step's
+    token of every row is one contiguous (B, g, hd) block, written in place
+    into the stack the layer scan carries, and decode attention reads the
+    layer's slice straight from it (layouts pinned, `carried`)."""
+    shape = (n_layers, max_len, batch, n_kv, head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            "idx": jnp.zeros((n_layers,), jnp.int32)}
 
 
-def init_slot_kv_cache(slots: int, max_len: int, n_kv: int, head_dim: int,
-                       dtype=jnp.bfloat16) -> Dict:
+def init_slot_kv_cache(n_layers: int, slots: int, max_len: int, n_kv: int,
+                       head_dim: int, dtype=jnp.bfloat16) -> Dict:
     """Slot-mapped decode cache for in-flight (continuous) batching.
 
-    Same K/V layout as init_kv_cache but `idx` is a (slots,) *per-slot*
-    write cursor: every slot rides its own ring position, so requests at
-    different sequence offsets decode fused in one batch.  attention_block
-    detects the vector cursor and switches to per-row scatter writes and
-    per-row masks.  Admit a request with write_slot_kv (scatter its
-    prefilled batch-1 cache into a slot), retire with free_slot_kv
-    (cursor reset only — the stale K/V rows are never moved or gathered)."""
-    return {"k": jnp.zeros((slots, max_len, n_kv, head_dim), dtype),
-            "v": jnp.zeros((slots, max_len, n_kv, head_dim), dtype),
-            "idx": jnp.zeros((slots,), jnp.int32)}
+    Same K/V layout as init_kv_cache but `idx` is (n_layers, slots): a
+    *per-slot* write cursor, so every slot rides its own ring position and
+    requests at different sequence offsets decode fused in one batch.
+    attention_block detects the vector cursor and switches to per-row
+    scatter writes and per-row masks.  Admit a request with write_slot_kv
+    (scatter its prefilled batch-1 cache into a slot), retire with
+    free_slot_kv (cursor reset only — the stale K/V rows are never moved
+    or gathered)."""
+    shape = (n_layers, max_len, slots, n_kv, head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            "idx": jnp.zeros((n_layers, slots), jnp.int32)}
 
 
 def write_slot_kv(cache: Dict, slot, prefill: Dict) -> Dict:
     """Admit one request: scatter its prefilled batch-1 KV cache (an
     init_kv_cache the request was prefilled into) into `slot` of a
-    slot-mapped cache and set the slot's cursor to the prefill length.
+    slot-mapped cache and set the slot's cursors to the prefill length.
     Leaves every other slot untouched — admission never perturbs the
     requests already in flight."""
-    return {"k": cache["k"].at[slot].set(
-                prefill["k"][0].astype(cache["k"].dtype)),
-            "v": cache["v"].at[slot].set(
-                prefill["v"][0].astype(cache["v"].dtype)),
-            "idx": cache["idx"].at[slot].set(
-                jnp.asarray(prefill["idx"], jnp.int32))}
+    return {"k": cache["k"].at[:, :, slot].set(
+                prefill["k"][:, :, 0].astype(cache["k"].dtype)),
+            "v": cache["v"].at[:, :, slot].set(
+                prefill["v"][:, :, 0].astype(cache["v"].dtype)),
+            "idx": cache["idx"].at[:, slot].set(prefill["idx"])}
 
 
 def free_slot_kv(cache: Dict, slot) -> Dict:
-    """Retire one request: reset the slot's write cursor to 0.
+    """Retire one request: reset the slot's write cursors to 0.
 
     Gather-free — the slot's stale K/V rows stay in place (a zero cursor
     masks every ring position out of the attention scores, and the next
     admit overwrites them), so retirement moves no cache data and cannot
     perturb the surviving requests."""
     return {"k": cache["k"], "v": cache["v"],
-            "idx": cache["idx"].at[slot].set(0)}
+            "idx": cache["idx"].at[:, slot].set(0)}
+
+
+def carried(stack: jnp.ndarray) -> jnp.ndarray:
+    """Pin a carried cache buffer to its stored (row-major) layout.  Left
+    free, XLA lays the layer scan's carry out as the attention of the
+    moment prefers — which changes with the batch, the cache length and
+    how the new token was projected — and then converts the whole stack at
+    the step's entry and exit; pinned, the step's only cache-sized ops are
+    the in-place writes."""
+    return with_layout_constraint(stack, Layout(tuple(range(stack.ndim))))
+
+
+def at_layer(stack, layer, fn):
+    """Run `fn` on layer `layer`'s slice of a stacked state tree (leading
+    layer axis) and write the slice it returns back whole at `layer`:
+    `fn(slice) -> (out, new_slice)`, returns (out, updated stack).  The
+    layer scan carries the stack, so the write lands in place.  With no
+    stack (no cache) it returns (fn(None)'s out, None)."""
+    if stack is None:
+        return fn(None)[0], None
+
+    def read(a):
+        return jax.lax.dynamic_index_in_dim(a, layer, keepdims=False)
+
+    def write(a, n):
+        return jax.lax.dynamic_update_index_in_dim(a, n.astype(a.dtype),
+                                                   layer, 0)
+
+    out, new = fn(jax.tree.map(read, stack))
+    return out, jax.tree.map(write, stack, new)
 
 
 # ---------------------------------------------------------------------------

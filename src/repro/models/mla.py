@@ -40,7 +40,7 @@ from repro.configs.base import ModelConfig
 from repro.core.cim_layers import (CIMConfig, analytic_log_gamma_init,
                                    cim_grouped_dense_apply,
                                    cim_linear_apply, init_cim_linear)
-from repro.models.common import apply_norm, init_norm
+from repro.models.common import apply_norm, carried, init_norm
 
 
 # ---------------------------------------------------------------------------
@@ -143,13 +143,23 @@ def absorbed_weights(p: Dict, c: ModelConfig) -> Tuple[Dict, Dict]:
     return uk, uv
 
 
-def init_latent_cache(batch: int, max_len: int, c: ModelConfig,
-                      dtype=jnp.bfloat16) -> Dict:
-    """One layer's latent cache: c_kv (B, L, rank), k_pe (B, L, rope) and
-    the shared write cursor.  `max_len` holds every position."""
-    return {"c_kv": jnp.zeros((batch, max_len, c.kv_lora_rank), dtype),
-            "k_pe": jnp.zeros((batch, max_len, c.qk_rope_head_dim), dtype),
-            "idx": jnp.array(0, jnp.int32)}
+def init_latent_cache(n_layers: int, batch: int, max_len: int,
+                      c: ModelConfig, dtype=jnp.bfloat16) -> Dict:
+    """A layer stack's latent cache: c_kv (n_layers, B, L, rank), k_pe
+    (n_layers, B, rope, L) and a shared write cursor a layer.  `max_len`
+    holds every position.
+
+    The layer scan carries the stack whole (layouts pinned, `carried`).
+    c_kv is batch-major, each row's (L, rank) block the operand of the
+    absorbed dots; k_pe holds its positions minor, the layout the TPU
+    gives an (L, 64) block where the step takes it in — stored (L, rope),
+    the pinned carry would differ from it and be converted at the step's
+    entry and exit."""
+    return {"c_kv": jnp.zeros((n_layers, batch, max_len, c.kv_lora_rank),
+                              dtype),
+            "k_pe": jnp.zeros((n_layers, batch, c.qk_rope_head_dim,
+                               max_len), dtype),
+            "idx": jnp.zeros((n_layers,), jnp.int32)}
 
 
 # ---------------------------------------------------------------------------
@@ -179,21 +189,22 @@ def _expanded(p: Dict, c: ModelConfig, cim: CIMConfig, q_nope, q_pe, c_kv,
 
 
 def _absorbed(p: Dict, c: ModelConfig, cim: CIMConfig, q_nope, q_pe,
-              cache: Dict) -> jnp.ndarray:
-    """One decode token per row against the whole latent cache, in the
-    absorbed form (module docstring); slots past the cursor are masked."""
+              c_kv, k_pe, idx) -> jnp.ndarray:
+    """One decode token per row against a layer's whole latent cache
+    (c_kv (B, L, rank), k_pe (B, rope, L)), in the absorbed form (module
+    docstring); slots at or past the cursor `idx` are masked."""
     b, _, h, _ = q_nope.shape
     dt = q_nope.dtype
     uk, uv = absorbed_weights(p, c)
-    ckv = cache["c_kv"].astype(jnp.float32)
-    kpe = cache["k_pe"].astype(jnp.float32)
+    ckv = c_kv.astype(jnp.float32)
+    kpe = k_pe.astype(jnp.float32)
     q_lat = cim_grouped_dense_apply(
         uk, jnp.swapaxes(q_nope[:, 0], 0, 1), cim)         # (H, B, rank)
     scores = (jnp.einsum("hbc,blc->bhl", q_lat.astype(jnp.float32), ckv)
-              + jnp.einsum("bhr,blr->bhl", q_pe[:, 0].astype(jnp.float32),
+              + jnp.einsum("bhr,brl->bhl", q_pe[:, 0].astype(jnp.float32),
                            kpe))
     scores = scores * softmax_scale(c)
-    valid = jnp.arange(ckv.shape[1]) < cache["idx"]
+    valid = jnp.arange(ckv.shape[1]) < idx
     scores = jnp.where(valid[None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bhl,blc->hbc", probs, ckv).astype(dt)
@@ -202,9 +213,12 @@ def _absorbed(p: Dict, c: ModelConfig, cim: CIMConfig, q_nope, q_pe,
 
 
 def mla_block(p: Dict, x: jnp.ndarray, c: ModelConfig, cim: CIMConfig, *,
-              positions: jnp.ndarray, cache: Optional[Dict] = None
-              ) -> Tuple[jnp.ndarray, Optional[Dict]]:
-    """x (B, S, d) -> (out (B, S, d), updated latent cache or None)."""
+              positions: jnp.ndarray, cache: Optional[Dict] = None,
+              layer=0) -> Tuple[jnp.ndarray, Optional[Dict]]:
+    """x (B, S, d) -> (out (B, S, d), updated latent cache or None).
+
+    `cache` is the whole layer stack's (init_latent_cache); `layer` (an int
+    or a traced index) picks this layer's slice, written in place."""
     with jax.named_scope("lm.attention"):
         b, s, _ = x.shape
         h, r, nope = c.n_heads, c.kv_lora_rank, c.qk_nope_head_dim
@@ -218,17 +232,26 @@ def mla_block(p: Dict, x: jnp.ndarray, c: ModelConfig, cim: CIMConfig, *,
         new_cache = None
         if cache is not None:
             with jax.named_scope("lm.kv_write"):
-                idx = cache["idx"]
+                idx = cache["idx"][layer]
                 new_cache = {
-                    "c_kv": jax.lax.dynamic_update_slice(
-                        cache["c_kv"], c_kv.astype(cache["c_kv"].dtype),
-                        (0, idx, 0)),
-                    "k_pe": jax.lax.dynamic_update_slice(
-                        cache["k_pe"], k_pe.astype(cache["k_pe"].dtype),
-                        (0, idx, 0)),
-                    "idx": idx + s}
+                    "c_kv": carried(jax.lax.dynamic_update_slice(
+                        cache["c_kv"],
+                        c_kv.astype(cache["c_kv"].dtype)[None],
+                        (layer, 0, idx, 0))),
+                    "k_pe": carried(jax.lax.dynamic_update_slice(
+                        cache["k_pe"],
+                        jnp.swapaxes(k_pe, 1, 2).astype(
+                            cache["k_pe"].dtype)[None],
+                        (layer, 0, 0, idx))),
+                    "idx": cache["idx"].at[layer].add(s)}
         if cache is not None and s == 1:
-            out = _absorbed(p, c, cim, q_nope, q_pe, new_cache)
+            out = _absorbed(
+                p, c, cim, q_nope, q_pe,
+                jax.lax.dynamic_index_in_dim(new_cache["c_kv"], layer,
+                                             keepdims=False),
+                jax.lax.dynamic_index_in_dim(new_cache["k_pe"], layer,
+                                             keepdims=False),
+                idx + s)
         else:
             if cache is not None:
                 # the prompt reads its latents as decode will: rounded to

@@ -193,13 +193,14 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict:
 # ---------------------------------------------------------------------------
 
 def _decoder_layer(cfg: ModelConfig, p: Dict, x: jnp.ndarray, *,
-                   positions: jnp.ndarray, cache: Optional[Dict],
+                   positions: jnp.ndarray, cache: Optional[Dict], layer,
                    key: Optional[jax.Array] = None, dense: bool = False
                    ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
-    """One pre-norm decoder layer (attention + MLP-or-MoE FFN); `key`
-    seeds the CIM noise model of the layer's projections (distinct folds
-    for the attention and FFN banks).  `dense` marks a MoE stack's
-    leading dense layer."""
+    """One pre-norm decoder layer (attention + MLP-or-MoE FFN); `cache` is
+    the whole stack's, `layer` this layer's index in it.  `key` seeds the
+    CIM noise model of the layer's projections (distinct folds for the
+    attention and FFN banks).  `dense` marks a MoE stack's leading dense
+    layer."""
     cim = cfg.cim
     k_attn = k_ffn = None
     if key is not None:
@@ -209,11 +210,11 @@ def _decoder_layer(cfg: ModelConfig, p: Dict, x: jnp.ndarray, *,
     if cfg.kv_lora_rank:
         attn_out, new_kv = mla.mla_block(
             p["attn"], h, cfg, cim, positions=positions,
-            cache=kv)
+            cache=kv, layer=layer)
     else:
         attn_out, new_kv = cm.attention_block(
             p["attn"], h, _attn_cfg(cfg, window=cfg.sliding_window), cim,
-            positions=positions, cache=kv, key=k_attn)
+            positions=positions, cache=kv, layer=layer, key=k_attn)
     x = x + attn_out
     h = cm.apply_norm(p["ln2"], x, cfg.norm_type)
     if cfg.family == "moe" and not dense and cfg.moe_held:
@@ -236,31 +237,40 @@ def _decoder_layer(cfg: ModelConfig, p: Dict, x: jnp.ndarray, *,
     return x, new_cache, jnp.asarray(aux, jnp.float32)
 
 
-def _ssm_layer(cfg: ModelConfig, p: Dict, x, *, positions, cache,
+def _ssm_layer(cfg: ModelConfig, p: Dict, x, *, positions, cache, layer,
                key: Optional[jax.Array] = None):
     h = cm.apply_norm(p["ln1"], x, cfg.norm_type)
-    out, new_state = m2.mamba2_layer(
-        p["mixer"], h, cfg, cfg.cim,
-        state=None if cache is None else cache["ssm"])
+
+    def mixer(state):
+        return m2.mamba2_layer(p["mixer"], h, cfg, cfg.cim, state=state)
+
+    out, new_state = cm.at_layer(None if cache is None else cache["ssm"],
+                                 layer, mixer)
     new_cache = None if cache is None else {"ssm": new_state}
     return x + out, new_cache, jnp.float32(0.0)
 
 
-def _rec_layer(cfg: ModelConfig, p: Dict, x, *, cache):
+def _rec_layer(cfg: ModelConfig, p: Dict, x, *, cache, layer):
     h = cm.apply_norm(p["ln1"], x, cfg.norm_type)
-    out, new_state = rg.rglru_block(
-        p["rec"], h, cfg.cim, state=None if cache is None else cache["rec"])
+
+    def rec(state):
+        return rg.rglru_block(p["rec"], h, cfg.cim, state=state)
+
+    out, new_state = cm.at_layer(None if cache is None else cache["rec"],
+                                 layer, rec)
     x = x + out
     h = cm.apply_norm(p["ln2"], x, cfg.norm_type)
     x = x + cm.mlp_block(p["mlp"], h, cfg.cim, cfg.mlp_act)
     return x, (None if cache is None else {"rec": new_state})
 
 
-def _local_attn_layer(cfg: ModelConfig, p: Dict, x, *, positions, cache):
+def _local_attn_layer(cfg: ModelConfig, p: Dict, x, *, positions, cache,
+                      layer):
     h = cm.apply_norm(p["ln1"], x, cfg.norm_type)
     out, new_kv = cm.attention_block(
         p["attn"], h, _attn_cfg(cfg, window=cfg.local_window), cfg.cim,
-        positions=positions, cache=None if cache is None else cache["kv"])
+        positions=positions, cache=None if cache is None else cache["kv"],
+        layer=layer)
     x = x + out
     h = cm.apply_norm(p["ln2"], x, cfg.norm_type)
     x = x + cm.mlp_block(p["mlp"], h, cfg.cim, cfg.mlp_act)
@@ -272,8 +282,13 @@ def _local_attn_layer(cfg: ModelConfig, p: Dict, x, *, positions, cache):
 # ---------------------------------------------------------------------------
 
 def _scan_stack(layer_fn, stacked_params, x, cache, remat: bool,
-                policy: str = "full"):
-    """lax.scan over stacked layer params (+ optionally stacked cache)."""
+                policy: str = "full", first: int = 0):
+    """lax.scan over stacked layer params.  The stacked cache (or None)
+    rides in the carry, never as xs/ys: `layer_fn(p, x, cache, i)` gets it
+    whole with the layer's index `i` in it (the scan's layers are cache
+    layers first, first + 1, ...) and returns it updated, so each layer's
+    write lands in place and no per-layer slice of the cache is
+    restacked."""
     if remat and policy == "dots":
         fn = jax.checkpoint(
             layer_fn,
@@ -282,75 +297,58 @@ def _scan_stack(layer_fn, stacked_params, x, cache, remat: bool,
         fn = jax.checkpoint(layer_fn)
     else:
         fn = layer_fn
+    n_layers = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
 
     def body(carry, xs):
-        x, aux = carry
-        p, c = xs
+        x, aux, c = carry
+        p, i = xs
         with jax.named_scope("lm.layer"):
-            new_x, new_c, a = fn(p, x, c)
-            return (new_x.astype(x.dtype), aux + a), new_c
+            new_x, new_c, a = fn(p, x, c, i)
+            return (new_x.astype(x.dtype), aux + a, new_c), None
 
-    (x, aux), new_cache = jax.lax.scan(
-        body, (x, jnp.float32(0.0)), (stacked_params, cache))
-    return x, new_cache, aux
+    (x, aux, cache), _ = jax.lax.scan(
+        body, (x, jnp.float32(0.0), cache),
+        (stacked_params, jnp.arange(first, first + n_layers,
+                                    dtype=jnp.int32)))
+    return x, cache, aux
 
 
 def _decoder_stack(cfg: ModelConfig, params, x, positions, cache, key=None):
     layer = {"dense": _decoder_layer, "moe": _decoder_layer,
              "vlm": _decoder_layer, "ssm": _ssm_layer}[cfg.family]
 
+    def stack(p, x, c, dense=False, first=0):
+        def f(p, x, c, i):
+            # a noise-keyed run folds a distinct key per layer index (the
+            # scan body sees a traced index, so one trace covers every
+            # layer)
+            k = None if key is None else jax.random.fold_in(key, i)
+            kw = {"dense": True} if dense else {}
+            return layer(cfg, p, x, positions=positions, cache=c, layer=i,
+                         key=k, **kw)
+
+        return _scan_stack(f, p, x, c, cfg.remat, cfg.remat_policy, first)
+
     if cfg.dense_layers:
-        # the leading dense layers, then the scanned MoE stack; the cache
-        # holds one stack of each
-        def f_dense(p, x, c):
-            return layer(cfg, p, x, positions=positions, cache=c,
-                         dense=True)
-
-        def f_moe(p, x, c):
-            return layer(cfg, p, x, positions=positions, cache=c)
-
-        x, new_dense, aux0 = _scan_stack(
-            f_dense, params["dense_layers"], x,
-            None if cache is None else cache["dense"], cfg.remat,
-            cfg.remat_policy)
-        x, new_moe, aux = _scan_stack(
-            f_moe, params["layers"], x,
-            None if cache is None else cache["moe"], cfg.remat,
-            cfg.remat_policy)
-        new_cache = (None if cache is None
-                     else {"dense": new_dense, "moe": new_moe})
-        return x, new_cache, aux0 + aux
-
-    if key is None:
-        def f(p, x, c):
-            return layer(cfg, p, x, positions=positions, cache=c)
-
-        return _scan_stack(f, params["layers"], x, cache, cfg.remat,
-                           cfg.remat_policy)
-
-    # noise-keyed run: fold a distinct key per layer index (the scan body
-    # sees a traced index, so one trace covers every layer)
-    n_layers = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
-
-    def f_keyed(px, x, c):
-        p, idx = px
-        return layer(cfg, p, x, positions=positions, cache=c,
-                     key=jax.random.fold_in(key, idx))
-
-    return _scan_stack(f_keyed, (params["layers"],
-                                 jnp.arange(n_layers, dtype=jnp.int32)),
-                       x, cache, cfg.remat, cfg.remat_policy)
+        # the leading dense layers, then the scanned MoE stack; both scans
+        # carry the one cache stack of every layer, the MoE layers after
+        # the dense ones
+        x, cache, aux0 = stack(params["dense_layers"], x, cache, dense=True)
+        x, cache, aux = stack(params["layers"], x, cache,
+                              first=cfg.dense_layers)
+        return x, cache, aux0 + aux
+    return stack(params["layers"], x, cache)
 
 
 def _hybrid_stack(cfg: ModelConfig, params, x, positions, cache):
-    def block_fn(p, x, c):
+    def block_fn(p, x, c, i):
         c1 = None if c is None else c["rec1"]
         c2 = None if c is None else c["rec2"]
         c3 = None if c is None else c["attn"]
-        x, nc1 = _rec_layer(cfg, p["rec1"], x, cache=c1)
-        x, nc2 = _rec_layer(cfg, p["rec2"], x, cache=c2)
+        x, nc1 = _rec_layer(cfg, p["rec1"], x, cache=c1, layer=i)
+        x, nc2 = _rec_layer(cfg, p["rec2"], x, cache=c2, layer=i)
         x, nc3 = _local_attn_layer(cfg, p["attn"], x,
-                                   positions=positions, cache=c3)
+                                   positions=positions, cache=c3, layer=i)
         nc = None if c is None else {"rec1": nc1, "rec2": nc2, "attn": nc3}
         return x, nc, jnp.float32(0.0)
 
@@ -359,8 +357,8 @@ def _hybrid_stack(cfg: ModelConfig, params, x, positions, cache):
 
     new_tail = None
     if "tail" in params:
-        def tail_fn(p, x, c):
-            x, nc = _rec_layer(cfg, p, x, cache=c)
+        def tail_fn(p, x, c, i):
+            x, nc = _rec_layer(cfg, p, x, cache=c, layer=i)
             return x, nc, jnp.float32(0.0)
         tc = None if cache is None else cache["tail"]
         x, new_tail, _ = _scan_stack(tail_fn, params["tail"], x, tc, cfg.remat)
@@ -431,7 +429,9 @@ def forward(cfg: ModelConfig, params, tokens: jnp.ndarray, *,
     inner_cache = None if cache is None else cache["layers"]
     if positions is None:
         if cache is not None:
-            positions = cache["pos"] + jnp.arange(s)
+            # (S,) from a shared position; (B, S) from a slot-mapped
+            # cache's per-slot positions
+            positions = cache["pos"][..., None] + jnp.arange(s)
         else:
             positions = jnp.arange(s)
 
@@ -466,7 +466,7 @@ def _audio_forward(cfg, params, x, positions, cache, encoder_frames):
         enc = enc + _sinusoid(enc.shape[1], cfg.d_model).astype(x.dtype)
         enc_pos = jnp.arange(enc.shape[1])
 
-        def enc_fn(p, h, c):
+        def enc_fn(p, h, c, i):
             hh = cm.apply_norm(p["ln1"], h, cfg.norm_type)
             out, _ = cm.attention_block(
                 p["attn"], hh, _attn_cfg(cfg, causal=False, use_rope=False),
@@ -480,18 +480,25 @@ def _audio_forward(cfg, params, x, positions, cache, encoder_frames):
                                 cfg.remat)
         enc = cm.apply_norm(params["enc_norm"], enc, cfg.norm_type)
 
-    def dec_fn(p, h, c):
+    def dec_fn(p, h, c, i):
         hh = cm.apply_norm(p["ln1"], h, cfg.norm_type)
         out, nkv = cm.attention_block(
             p["attn"], hh, _attn_cfg(cfg, use_rope=False), cfg.cim,
-            positions=positions, cache=None if c is None else c["kv"])
+            positions=positions, cache=None if c is None else c["kv"],
+            layer=i)
         h = h + out
         hh = cm.apply_norm(p["ln_x"], h, cfg.norm_type)
-        xkv_in = None if (c is None or enc is not None) else c["xkv"]
-        out, nxkv = cm.attention_block(
-            p["xattn"], hh, _attn_cfg(cfg, causal=False, use_rope=False),
-            cfg.cim, positions=positions, x_kv=enc,
-            cross_kv=xkv_in, cache={} if c is not None else None)
+
+        def xattn(xkv):
+            # cross K/V: fresh from the encoder (train/prefill), else the
+            # layer's cached slice
+            return cm.attention_block(
+                p["xattn"], hh, _attn_cfg(cfg, causal=False, use_rope=False),
+                cfg.cim, positions=positions, x_kv=enc,
+                cross_kv=None if enc is not None else xkv,
+                cache={} if xkv is not None else None)
+
+        out, nxkv = cm.at_layer(None if c is None else c["xkv"], i, xattn)
         h = h + out
         hh = cm.apply_norm(p["ln2"], h, cfg.norm_type)
         h = h + cm.mlp_block(p["mlp"], hh, cfg.cim, cfg.mlp_act)
@@ -523,7 +530,9 @@ def _kv_cache_len(cfg: ModelConfig, max_len: int, window: int) -> int:
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                dtype=jnp.bfloat16) -> Dict:
-    """Decode cache pytree: {"pos": scalar, "layers": stacked per-layer}."""
+    """Decode cache pytree: {"pos": scalar, "layers": stacked per-layer},
+    attention caches in the axis order of cm.init_kv_cache and
+    mla.init_latent_cache; the layer scan carries each stack whole."""
     hd = cfg.resolved_head_dim if cfg.n_heads else 0
     g = cfg.n_kv_heads
 
@@ -532,20 +541,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
             lambda a: jnp.broadcast_to(a, (n,) + a.shape), tree)
 
     def kv(n, length):
-        return stack(cm.init_kv_cache(batch, length, g, hd, dtype), n)
+        return cm.init_kv_cache(n, batch, length, g, hd, dtype)
 
     pos = jnp.array(0, jnp.int32)
     if cfg.family in ("dense", "moe", "vlm") and cfg.kv_lora_rank:
         # latent attention: c_kv and k_pe a token a layer, never per head
-        def latent(n):
-            return stack(mla.init_latent_cache(batch, max_len, cfg, dtype),
-                         n)
-
-        if not cfg.dense_layers:
-            return {"pos": pos, "layers": {"kv": latent(cfg.n_layers)}}
-        return {"pos": pos, "layers": {
-            "dense": {"kv": latent(cfg.dense_layers)},
-            "moe": {"kv": latent(cfg.n_layers - cfg.dense_layers)}}}
+        return {"pos": pos, "layers": {"kv": mla.init_latent_cache(
+            cfg.n_layers, batch, max_len, cfg, dtype)}}
     if cfg.family in ("dense", "moe", "vlm"):
         length = _kv_cache_len(cfg, max_len, cfg.sliding_window)
         return {"pos": pos, "layers": {"kv": kv(cfg.n_layers, length)}}
@@ -577,8 +579,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
 def init_slot_cache(cfg: ModelConfig, slots: int, max_len: int,
                     dtype=jnp.bfloat16) -> Dict:
     """Slot-mapped decode cache for in-flight (continuous) batching:
-    {"pos": (slots,) per-slot position, "layers": stacked per-layer
-    cm.init_slot_kv_cache} — every slot rides its own ring cursor, so
+    {"pos": (slots,) per-slot position, "layers": {"kv":
+    cm.init_slot_kv_cache}} — every slot rides its own ring cursor, so
     requests at different sequence offsets decode fused in one batch.
     Attention-cache families only (dense/moe/vlm)."""
     if cfg.family not in ("dense", "moe", "vlm") or cfg.kv_lora_rank \
@@ -586,11 +588,9 @@ def init_slot_cache(cfg: ModelConfig, slots: int, max_len: int,
         raise ValueError(
             f"slot-mapped decode supports the per-head KV caches of "
             f"dense/moe/vlm stacks, not {cfg.name!r}")
-    hd = cfg.resolved_head_dim
     length = _kv_cache_len(cfg, max_len, cfg.sliding_window)
-    kvs = jax.tree.map(
-        lambda a: jnp.broadcast_to(a, (cfg.n_layers,) + a.shape),
-        cm.init_slot_kv_cache(slots, length, cfg.n_kv_heads, hd, dtype))
+    kvs = cm.init_slot_kv_cache(cfg.n_layers, slots, length, cfg.n_kv_heads,
+                                cfg.resolved_head_dim, dtype)
     return {"pos": jnp.zeros((slots,), jnp.int32), "layers": {"kv": kvs}}
 
 
@@ -598,19 +598,15 @@ def write_slot_cache(cache: Dict, slot: int, prefill: Dict) -> Dict:
     """Admit a prefilled request into slot `slot` of a slot-mapped cache:
     scatter the batch-1 `prefill` cache's K/V rings, per-layer cursors and
     position into the slot (gather-free; every other slot untouched)."""
-    pkv, kv = prefill["layers"]["kv"], cache["layers"]["kv"]
-    new = {"k": kv["k"].at[:, slot].set(pkv["k"][:, 0].astype(kv["k"].dtype)),
-           "v": kv["v"].at[:, slot].set(pkv["v"][:, 0].astype(kv["v"].dtype)),
-           "idx": kv["idx"].at[:, slot].set(pkv["idx"])}
+    kv = cm.write_slot_kv(cache["layers"]["kv"], slot,
+                          prefill["layers"]["kv"])
     return {"pos": cache["pos"].at[slot].set(prefill["pos"]),
-            "layers": {"kv": new}}
+            "layers": {"kv": kv}}
 
 
 def free_slot_cache(cache: Dict, slot: int) -> Dict:
     """Retire the request in slot `slot`: reset its cursors/position only
     (its K/V rows stay in place until the next admission overwrites them —
     per-row masks keep dead rows invisible to everyone else)."""
-    kv = cache["layers"]["kv"]
     return {"pos": cache["pos"].at[slot].set(0),
-            "layers": {"kv": {**kv,
-                              "idx": kv["idx"].at[:, slot].set(0)}}}
+            "layers": {"kv": cm.free_slot_kv(cache["layers"]["kv"], slot)}}

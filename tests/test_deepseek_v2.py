@@ -94,19 +94,22 @@ def test_decode_matches_the_hf_full_forward(toks):
 
 
 def test_cache_holds_latents_only():
-    """A token of a layer caches c_kv and k_pe: rank + rope values."""
+    """A token of a layer caches c_kv and k_pe: rank + rope values
+    (c_kv (n_layers, B, L, rank), k_pe (n_layers, B, rope, L)), in one
+    stack of every layer, the dense prefix's included."""
     cfg = get_smoke_config("deepseek-v2-lite")
     cache = tf.init_cache(cfg, 2, max_len=16)
-    kv = cache["layers"]["moe"]["kv"]
+    kv = cache["layers"]["kv"]
     assert set(kv) == {"c_kv", "k_pe", "idx"}
-    per_token = kv["c_kv"].shape[-1] + kv["k_pe"].shape[-1]
+    assert kv["c_kv"].shape[:3] == (cfg.n_layers, 2, 16)
+    assert kv["k_pe"].shape[:2] + kv["k_pe"].shape[3:] == (cfg.n_layers, 2,
+                                                           16)
+    per_token = kv["c_kv"].shape[-1] + kv["k_pe"].shape[-2]
     assert per_token == cfg.kv_lora_rank + cfg.qk_rope_head_dim
     full = jax.eval_shape(lambda: tf.init_cache(
-        get_config("deepseek-v2-lite"), 1, 8))["layers"]
-    assert full["dense"]["kv"]["c_kv"].shape[0] == 1
-    assert full["moe"]["kv"]["c_kv"].shape[0] == 26
-    assert (full["moe"]["kv"]["c_kv"].shape[-1]
-            + full["moe"]["kv"]["k_pe"].shape[-1]) == 576
+        get_config("deepseek-v2-lite"), 1, 8))["layers"]["kv"]
+    assert full["c_kv"].shape[0] == full["k_pe"].shape[0] == 27
+    assert full["c_kv"].shape[-1] + full["k_pe"].shape[-2] == 576
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -122,7 +125,7 @@ def test_absorbed_decode_equals_expanded(dtype):
     dt = jnp.dtype(dtype)
     xd = x.astype(dt)
     pd = jax.tree.map(lambda a: a.astype(dt), p)
-    cache = mla.init_latent_cache(B, T, cfg, dt)
+    cache = mla.init_latent_cache(1, B, T, cfg, dt)
     _, cache = mla.mla_block(pd, xd[:, :T - 1], cfg, cfg.cim,
                              positions=pos[:T - 1], cache=cache)
     got, _ = mla.mla_block(pd, xd[:, T - 1:], cfg, cfg.cim,

@@ -176,3 +176,133 @@ def test_cim_mbiw_grouped_call_compiles(one_chip, groups, blocks, k, n):
                    shape((groups, k, n), jnp.float32),
                    shape((blocks, n), jnp.float32),
                    shape((blocks, n), jnp.float32), idx, idx, idx)
+
+
+# the decode step at the decode cells' cache shapes: the stacked cache
+# rides the layer scan's carry, so its only cache-sized ops are the
+# in-place writes of the step's token.  (d_model, n_heads, n_layers) cut
+# to keep the compile short; batch, positions and the cache's per-token
+# widths are the cells'.
+OLMO_CUT = dict(n_layers=4, d_model=512, n_heads=4, n_kv_heads=4, d_ff=1024,
+                vocab_size=1024)
+SERVE_CASES = {
+    # olmo-1b's decode cell: 64 x 384 positions, heads of 128
+    "dense": ("olmo-1b", OLMO_CUT, 64, 384, False),
+    # olmo-1b's prefill cell decoding: 8 x 1032, where XLA left free would
+    # carry the stack in another layout than it is stored in
+    "dense_b8": ("olmo-1b", OLMO_CUT, 8, 1032, False),
+    # DeepSeek-V2-Lite: one dense layer, then three MoE; the latent cache
+    # of kv_lora_rank 512 + qk_rope_head_dim 64 a token, 256 x 384
+    "latent": ("deepseek-v2-lite", dict(n_layers=4, kv_lora_rank=512,
+                                        qk_rope_head_dim=64),
+               256, 384, False),
+    # the slot-mapped cache of in-flight batching (per-slot cursors)
+    "slot": ("olmo-1b", OLMO_CUT, 64, 384, True),
+}
+
+
+def _cache_sized_ops(text: str, cache) -> list:
+    """Ops outside fusion bodies whose output (or any tuple part of it)
+    holds as many cache-dtype elements as one layer's slice or the whole
+    stack of some cache leaf — layout copies, restacked slices, copies of
+    the whole cache — except in-place writes: a `dynamic-update-slice` or
+    `scatter`, or a fusion whose root is one, of an update smaller than a
+    layer."""
+    import re
+
+    import numpy as np
+
+    dtype = {str(a.dtype) for a in jax.tree.leaves(cache) if a.ndim >= 3}
+    hlo_dt = {"bfloat16": "bf16", "float32": "f32"}
+    dts = {hlo_dt[d] for d in dtype}
+    layer = {int(np.prod(a.shape[1:])) for a in jax.tree.leaves(cache)
+             if a.ndim >= 3}
+    sizes = layer | {int(np.prod(a.shape)) for a in jax.tree.leaves(cache)
+                     if a.ndim >= 3}
+    shape_re = re.compile(r"(\w+)\[([\d,]*)\]")
+    op_re = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)")
+
+    def n_elems(dims: str) -> int:
+        return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None and line.strip() not in ("", "}"):
+            cur.append(line)
+    bodies = set(re.findall(r"calls=%([\w.\-]+)", text))
+
+    # operand that holds the written values, of each in-place write
+    update_at = {"dynamic-update-slice": 1, "scatter": 2}
+
+    def token_write(op: str, args: str, comp_lines) -> bool:
+        lines, operands = comp_lines, args
+        if op == "fusion":
+            called = re.search(r"calls=%([\w.\-]+)", args).group(1)
+            lines = comps[called]
+            root = next(op_re.match(ln) for ln in lines if "ROOT" in ln)
+            op, operands = root.group(3), root.group(4)
+        if op not in update_at:
+            return False
+        update = re.findall(r"%([\w.\-]+)", operands)[update_at[op]]
+        shp = next(m.group(2) for m in map(op_re.match, lines)
+                   if m and m.group(1) == update)
+        return n_elems(shape_re.search(shp).group(2)) < min(layer)
+
+    found = []
+    for name, lines in comps.items():
+        if name in bodies:
+            continue
+        for ln in lines:
+            m = op_re.match(ln)
+            if not m or m.group(3) in ("parameter", "bitcast", "tuple",
+                                       "get-tuple-element", "while",
+                                       "call", "conditional"):
+                continue
+            if any(dt in dts and n_elems(dims) in sizes
+                   for dt, dims in shape_re.findall(m.group(2))):
+                if not token_write(m.group(3), m.group(4), lines):
+                    found.append(f"{m.group(3)} {m.group(1)} {m.group(2)}")
+    return found
+
+
+@pytest.mark.parametrize("case", sorted(SERVE_CASES))
+def test_decode_step_writes_the_cache_in_place(one_chip, case):
+    """The compiled, donated decode step copies no cache-sized buffer: no
+    layout copy at its entry or exit, no per-layer slice restacked, no
+    whole-cache copy — only the in-place writes of the step's token —
+    and every cache leaf is aliased from input to output."""
+    import re
+
+    from repro.configs import get_config, get_smoke_config
+    from repro.core.cim_layers import CIMConfig
+    from repro.launch.steps import make_serve_step
+    from repro.models import transformer as tf
+
+    name, cut, batch, max_len, slot = SERVE_CASES[case]
+    base = (get_smoke_config(name) if name == "deepseek-v2-lite"
+            else get_config(name))
+    cfg = base.replace(**cut, dtype="float32", cim=CIMConfig(
+        mode="engine", max_gamma=2.0 ** 16))
+    params = jax.eval_shape(lambda: tf.init_params(cfg,
+                                                   jax.random.PRNGKey(0)))
+    init = tf.init_slot_cache if slot else tf.init_cache
+    cache = jax.eval_shape(lambda: init(cfg, batch, max_len))
+
+    def shape(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    step = jax.jit(make_serve_step(cfg), donate_argnums=(1,),
+                   keep_unused=True)
+    text = step.lower(jax.tree.map(shape, params), jax.tree.map(shape, cache),
+                      shape(jax.ShapeDtypeStruct((batch, 1), jnp.int32))
+                      ).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the TPU program"
+    assert _cache_sized_ops(text, cache) == []
+    alias = text[text.index("input_output_alias="):].split("\n")[0]
+    aliased = {int(p) for p in re.findall(r"\{[\d,]*\}: \((\d+),", alias)}
+    first = len(jax.tree.leaves(params))
+    n_cache = len(jax.tree.leaves(cache))
+    assert set(range(first, first + n_cache)) <= aliased
